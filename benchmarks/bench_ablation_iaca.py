@@ -40,7 +40,10 @@ def _artifact(arch, shape, strategy):
 
 
 def _compile_arm(arch, shape, strategy):
-    env = dict(os.environ)
+    # The dry-run compiles for a 256-device mesh of forced host devices:
+    # the child stays on the CPU and never reaches for an accelerator
+    # this process may hold.
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = str(Path(__file__).resolve().parents[1] / "src")
     subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,

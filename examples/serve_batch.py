@@ -14,9 +14,10 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--gen", type=int, default=24)
     args = ap.parse_args()
-    serve_main(["--arch", args.arch, "--smoke", "--batch",
-                str(args.batch), "--prompt-len", "16", "--gen",
-                str(args.gen), "--temperature", "0.8"])
+    serve_main(["--arch", args.arch, "--smoke", "--slots",
+                str(args.batch), "--prompt-len-range", "16", "16",
+                "--gen-range", str(args.gen), str(args.gen),
+                "--temperature", "0.8"])
 
 
 if __name__ == "__main__":

@@ -51,11 +51,7 @@ class CheckpointCorruptionError(RuntimeError):
 
 
 def _flatten(tree: Any) -> tuple[list[tuple[str, Any]], Any]:
-    # jax.tree.flatten_with_path only exists on newer JAX; the tree_util
-    # spelling works on every version this repo supports.
-    flatten_with_path = getattr(jax.tree, "flatten_with_path", None) \
-        or jax.tree_util.tree_flatten_with_path
-    leaves, treedef = flatten_with_path(tree)
+    leaves, treedef = jax.tree.flatten_with_path(tree)
     named = [("/".join(str(getattr(k, "key", getattr(k, "idx", k)))
                        for k in path), leaf) for path, leaf in leaves]
     return named, treedef

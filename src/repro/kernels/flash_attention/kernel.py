@@ -21,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import CompilerParams
+from .. import resolve_interpret
 
 NEG = -1e30
 
@@ -76,7 +76,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int | None = None,
                     q_block: int = 128, kv_block: int = 512,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """q (BH, G, Sq, Dh); k (BH, Skv, Dh); v (BH, Skv, Dv) →
     (BH, G, Sq, Dv).  BH = batch × kv_heads, G = query group size."""
     BH, G, Sq, Dh = q.shape
@@ -107,7 +107,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((G, q_block), jnp.float32),
             pltpu.VMEM((G, q_block, Dv), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -14,7 +14,7 @@ from .kernel import flash_attention
                                              "interpret"))
 def mha(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
         window: int | None = None, q_block: int = 128,
-        kv_block: int = 512, interpret: bool = True) -> jax.Array:
+        kv_block: int = 512, interpret: bool | None = None) -> jax.Array:
     """q (B,Sq,H,Dh); k/v (B,Skv,KVH,Dh) with GQA → (B,Sq,H,Dv)."""
     B, Sq, H, Dh = q.shape
     KVH = k.shape[2]

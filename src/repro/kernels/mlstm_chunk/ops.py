@@ -11,7 +11,7 @@ from .kernel import mlstm_chunk as _kernel
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def mlstm_chunk(q, k, v, i_pre, f_pre, *, chunk: int = 128,
-                interpret: bool = True):
+                interpret: bool | None = None):
     """q,k,v (B,S,H,Dh); i/f (B,S,H) → (B,S,H·Dh) f32."""
     B, S, H, Dh = q.shape
     def tok(x):

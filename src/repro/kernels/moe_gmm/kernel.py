@@ -8,7 +8,9 @@ Grid: (E, C_blocks, F_blocks, D_blocks) — the contraction (last) dim is
 sequential, accumulating into a VMEM f32 scratch tile; (E, C, F) tiles
 are parallel.  Block shapes default to the MXU-native 128×128×512 so the
 working set (x_tile + w_tile + acc) stays ≪ VMEM and every matmul dim is
-lane-aligned.  Rows beyond ``group_sizes[e]`` are masked at the epilogue.
+lane-aligned.  Rows beyond ``group_sizes[e]`` are masked at the epilogue;
+``group_sizes`` is scalar-prefetched into SMEM so the per-expert count is
+a scalar read, not a vector load at an unaligned offset.
 """
 from __future__ import annotations
 
@@ -19,7 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import CompilerParams
+from .. import resolve_interpret
 
 
 def _gmm_kernel(gs_ref, x_ref, w_ref, y_ref, acc_ref, *, c_block: int):
@@ -48,7 +50,7 @@ def _gmm_kernel(gs_ref, x_ref, w_ref, y_ref, acc_ref, *, c_block: int):
 
 def moe_gmm(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
             c_block: int = 128, f_block: int = 512, d_block: int = 512,
-            interpret: bool = True) -> jax.Array:
+            interpret: bool | None = None) -> jax.Array:
     """x (E, C, D) · w (E, D, F) with valid-row masking → (E, C, F)."""
     E, C, D = x.shape
     F = w.shape[-1]
@@ -60,20 +62,22 @@ def moe_gmm(x: jax.Array, w: jax.Array, group_sizes: jax.Array, *,
     kern = functools.partial(_gmm_kernel, c_block=c_block)
     return pl.pallas_call(
         kern,
-        grid=(E, C // c_block, F // f_block, D // d_block),
-        in_specs=[
-            pl.BlockSpec((E,), lambda e, c, f, d: (0,)),
-            pl.BlockSpec((1, c_block, d_block),
-                         lambda e, c, f, d: (e, c, d)),
-            pl.BlockSpec((1, d_block, f_block),
-                         lambda e, c, f, d: (e, d, f)),
-        ],
-        out_specs=pl.BlockSpec((1, c_block, f_block),
-                               lambda e, c, f, d: (e, c, f)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(E, C // c_block, F // f_block, D // d_block),
+            in_specs=[
+                pl.BlockSpec((1, c_block, d_block),
+                             lambda e, c, f, d, gs: (e, c, d)),
+                pl.BlockSpec((1, d_block, f_block),
+                             lambda e, c, f, d, gs: (e, d, f)),
+            ],
+            out_specs=pl.BlockSpec((1, c_block, f_block),
+                                   lambda e, c, f, d, gs: (e, c, f)),
+            scratch_shapes=[pltpu.VMEM((c_block, f_block), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((E, C, F), x.dtype),
-        scratch_shapes=[pltpu.VMEM((c_block, f_block), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=interpret,
-    )(group_sizes, x, w)
+        interpret=resolve_interpret(interpret),
+    )(group_sizes.astype(jnp.int32), x, w)

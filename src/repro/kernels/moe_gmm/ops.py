@@ -11,6 +11,6 @@ from .kernel import moe_gmm as _kernel
 @functools.partial(jax.jit, static_argnames=("c_block", "f_block",
                                              "d_block", "interpret"))
 def moe_gmm(x, w, group_sizes, *, c_block: int = 128, f_block: int = 512,
-            d_block: int = 512, interpret: bool = True):
+            d_block: int = 512, interpret: bool | None = None):
     return _kernel(x, w, group_sizes, c_block=c_block, f_block=f_block,
                    d_block=d_block, interpret=interpret)

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import CompilerParams
+from .. import resolve_interpret
 
 
 def _rms_kernel(x_ref, s_ref, o_ref, *, eps: float):
@@ -24,7 +24,8 @@ def _rms_kernel(x_ref, s_ref, o_ref, *, eps: float):
 
 
 def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
-            row_block: int = 256, interpret: bool = True) -> jax.Array:
+            row_block: int = 256,
+            interpret: bool | None = None) -> jax.Array:
     """x (R, D), scale (D,) → (R, D)."""
     R, D = x.shape
     row_block = min(row_block, R)
@@ -37,7 +38,7 @@ def rmsnorm(x: jax.Array, scale: jax.Array, *, eps: float = 1e-6,
                   pl.BlockSpec((D,), lambda i: (0,))],
         out_specs=pl.BlockSpec((row_block, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, D), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, scale)

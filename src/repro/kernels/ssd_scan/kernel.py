@@ -10,6 +10,14 @@ flops per element against ~8 bytes moved).
 
 Channel blocking keeps the VMEM working set at
 chunk·d_block·(2+N/…) ≪ 16 MiB and d_block a lane multiple (128).
+
+Layout for the TPU compiler: the state is carried transposed, hᵀ
+(N, d_block), so a time step's x/dt rows broadcast over sublanes and
+its B/C entries are (N, 1) columns.  A, B and C arrive transposed from
+the wrapper (Aᵀ (N, Din); Bᵀ, Cᵀ (B, N, S)); step ``t`` reads its x/dt
+rows from the refs with ``pl.ds`` and picks its B/C columns with a lane
+mask, and writes its y row straight into the output block — no value
+is sliced at a traced index.
 """
 from __future__ import annotations
 
@@ -20,10 +28,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import CompilerParams
+from .. import resolve_interpret
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *,
+def _ssd_kernel(x_ref, dt_ref, at_ref, bt_ref, ct_ref, y_ref, h_ref, *,
                 chunk: int):
     ci = pl.program_id(2)
 
@@ -31,29 +39,28 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *,
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    x = x_ref[0].astype(jnp.float32)       # (chunk, d_block)
-    dt = dt_ref[0].astype(jnp.float32)     # (chunk, d_block)
-    A = a_ref[...].astype(jnp.float32)     # (d_block, N)
-    Bm = b_ref[0].astype(jnp.float32)      # (chunk, N)
-    Cm = c_ref[0].astype(jnp.float32)      # (chunk, N)
+    At = at_ref[...].astype(jnp.float32)    # (N, d_block)
+    Bt = bt_ref[0].astype(jnp.float32)      # (N, chunk)
+    Ct = ct_ref[0].astype(jnp.float32)      # (N, chunk)
+    lane = jax.lax.broadcasted_iota(jnp.int32, Bt.shape, 1)
 
-    def step(t, carry):
-        h, y = carry
-        dA = jnp.exp(dt[t][:, None] * A)               # (d_block, N)
-        h = dA * h + (dt[t] * x[t])[:, None] * Bm[t][None, :]
-        y = y.at[t].set(h @ Cm[t])                     # (d_block,)
-        return h, y
+    def step(t, h):
+        x_t = x_ref[0, pl.ds(t, 1), :].astype(jnp.float32)    # (1, d_blk)
+        dt_t = dt_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
+        sel = lane == t
+        b_t = jnp.sum(jnp.where(sel, Bt, 0.0), axis=1, keepdims=True)
+        c_t = jnp.sum(jnp.where(sel, Ct, 0.0), axis=1, keepdims=True)
+        h = jnp.exp(dt_t * At) * h + b_t * (dt_t * x_t)       # (N, d_blk)
+        y_ref[0, pl.ds(t, 1), :] = jnp.sum(
+            h * c_t, axis=0, keepdims=True).astype(y_ref.dtype)
+        return h
 
-    h0 = h_ref[...]
-    y0 = jnp.zeros((chunk, x.shape[1]), jnp.float32)
-    h, y = jax.lax.fori_loop(0, chunk, step, (h0, y0))
-    h_ref[...] = h
-    y_ref[0, ...] = y.astype(y_ref.dtype)
+    h_ref[...] = jax.lax.fori_loop(0, chunk, step, h_ref[...])
 
 
 def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
              Cm: jax.Array, *, chunk: int = 128, d_block: int = 128,
-             interpret: bool = True) -> jax.Array:
+             interpret: bool | None = None) -> jax.Array:
     """x, dt (B,S,Din); A (Din,N); Bm,Cm (B,S,N) → y (B,S,Din) f32."""
     B, S, Din = x.shape
     N = A.shape[-1]
@@ -69,15 +76,15 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
         in_specs=[
             pl.BlockSpec((1, chunk, d_block), lambda b, d, c: (b, c, d)),
             pl.BlockSpec((1, chunk, d_block), lambda b, d, c: (b, c, d)),
-            pl.BlockSpec((d_block, N), lambda b, d, c: (d, 0)),
-            pl.BlockSpec((1, chunk, N), lambda b, d, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, N), lambda b, d, c: (b, c, 0)),
+            pl.BlockSpec((N, d_block), lambda b, d, c: (0, d)),
+            pl.BlockSpec((1, N, chunk), lambda b, d, c: (b, 0, c)),
+            pl.BlockSpec((1, N, chunk), lambda b, d, c: (b, 0, c)),
         ],
         out_specs=pl.BlockSpec((1, chunk, d_block),
                                lambda b, d, c: (b, c, d)),
         out_shape=jax.ShapeDtypeStruct((B, S, Din), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((d_block, N), jnp.float32)],
-        compiler_params=CompilerParams(
+        scratch_shapes=[pltpu.VMEM((N, d_block), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(x, dt, A, Bm, Cm)
+        interpret=resolve_interpret(interpret),
+    )(x, dt, A.T, Bm.swapaxes(1, 2), Cm.swapaxes(1, 2))

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run (deliverable e).
 
 For every (architecture × input shape) cell, lower + compile the real
@@ -17,11 +14,16 @@ Artifacts land in ``experiments/dryrun/<arch>__<shape>__<mesh>.json``;
 ``benchmarks/roofline.py`` renders the §Roofline table from them.
 
 Usage:
-    PYTHONPATH=src python -m repro.launch.dryrun --arch smollm-135m \
-        --shape train_4k [--multi-pod] [--all] [--strategy hida|naive|...]
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.dryrun \
+        --arch smollm-135m --shape train_4k [--multi-pod] [--all] \
+        [--strategy hida|naive|...]
+
+``main()`` forces 512 host (CPU) devices before the first device query;
+importing this module sets nothing.
 """
 import argparse
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -33,7 +35,7 @@ from ..core import MULTI_POD, SINGLE_POD, build_lm_graph, optimize
 from ..core.graph import model_flops_6nd, step_flops
 from ..core.plan import replicated_plan
 from .hlo_analysis import collective_bytes, hlo_op_histogram
-from .mesh import make_production_mesh, mesh_spec, set_mesh
+from .mesh import make_production_mesh, mesh_spec
 from .steps import build_prefill_step, build_serve_step, build_train_step
 
 ARTIFACT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
@@ -79,7 +81,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
     chips = mesh.devices.size
     t0 = time.perf_counter()
     try:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             if shape.mode == "train":
                 step = build_train_step(cfg, shape, mesh, plan,
                                         remat=remat,
@@ -153,6 +155,7 @@ def _save(result: dict) -> None:
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, choices=list_archs())
     ap.add_argument("--shape", default=None, choices=list(SHAPES))

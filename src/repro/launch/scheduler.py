@@ -54,6 +54,25 @@ import numpy as np
 #: by a live one); models are few and long-lived per process.
 _JIT_MEMO: dict[int, tuple[object, dict]] = {}
 
+def _step_compiler_options() -> dict:
+    """Keep a row's arithmetic the same at every batch width, so the
+    streamed tokens equal :func:`decode_offline`'s.
+
+    On the TPU, XLA's dot strength reduction rewrites the vector×matrix
+    products of a batch-1 step into VPU multiply-reduces (the compiled
+    smollm-360m decode step keeps 2 MXU convolutions of 8), whose sums
+    run in another order than the MXU's: batch-1 and batch-8 logits then
+    differ in the last bf16 bit, and a near-tied greedy argmax flips.
+    With it off, the logits of a v5e are bit-equal across widths.
+    (Disallowing excess precision as well breaks that equality again.)"""
+    if jax.default_backend() == "tpu":
+        return {"xla_tpu_enable_dot_strength_reduction": False}
+    return {}
+
+
+def _jit(fn):
+    return jax.jit(fn, compiler_options=_step_compiler_options())
+
 
 def _jit_cache(lm) -> dict:
     ent = _JIT_MEMO.get(id(lm))
@@ -66,11 +85,11 @@ def _jitted_step(lm):
     cache = _jit_cache(lm)
     fn = cache.get("step")
     if fn is None:
-        fn = cache["step"] = jax.jit(lm.decode_step)
+        fn = cache["step"] = _jit(lm.decode_step)
     return fn
 
 __all__ = ["Request", "ServeReport", "ContinuousBatcher", "decode_offline",
-           "run_static", "prefill_bucket"]
+           "greedy_margins", "run_static", "prefill_bucket"]
 
 #: Distinct fold tag for a request's (single) image draw, so it can
 #: never collide with a per-position draw.
@@ -301,7 +320,7 @@ class ContinuousBatcher:
                 logits, (lengths - 1)[None, :, None], axis=0)[0]
             return out, last                               # (k, vocab)
 
-        fn = cache[("prefill", bucket, k)] = jax.jit(prefill)
+        fn = cache[("prefill", bucket, k)] = _jit(prefill)
         return fn
 
     def _zero_cache(self, k: int):
@@ -509,6 +528,34 @@ def decode_offline(lm, params, req: Request, *, seed: int, s_max: int,
         out.append(tok)
         t += 1
     return out
+
+
+def greedy_margins(lm, params, req: Request, tokens: list[int], *,
+                   s_max: int) -> np.ndarray:
+    """Teacher-forced replay of ``tokens`` (a greedy stream for ``req``)
+    through :func:`decode_offline`'s path: for each emitted token, how far
+    its logit sits below the row maximum, in ulps of the logits' dtype at
+    that maximum (0 where it is the argmax).
+
+    Where streamed and offline tokens part, this tells a near-tie flipped
+    by a last-bit rounding difference (a few ulps) from a stream that
+    left the model (hundreds).  Token-stream frontends only."""
+    caches = lm.init_caches(1, s_max)
+    step = _jitted_step(lm)
+    feed = list(np.asarray(req.prompt).reshape(-1)) + list(tokens[:-1])
+    margins = np.zeros(len(tokens), np.float32)
+    for t, tok in enumerate(feed):
+        batch = {"pos": jnp.asarray(t, jnp.int32),
+                 "tokens": jnp.asarray(tok, jnp.int32).reshape(1, 1)}
+        logits, caches = step(params, batch, caches)
+        j = t - (req.prompt_len - 1)
+        if j >= 0:
+            eps = float(jnp.finfo(logits.dtype).eps)
+            row = np.asarray(logits[0, -1], np.float32)
+            top = float(row.max())
+            ulp = eps * 2.0 ** np.floor(np.log2(max(abs(top), 1e-30)))
+            margins[j] = (top - row[tokens[j]]) / ulp
+    return margins
 
 
 def run_static(lm, params, requests: list[Request], *, seed: int,

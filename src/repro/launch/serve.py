@@ -12,7 +12,9 @@ Pipeline per invocation:
    (statically re-verified against the mesh), a miss runs the DSE,
    warm-started from the nearest cached donor when one exists.  The
    cache root comes from ``--plan-cache`` or ``$REPRO_PLAN_CACHE``;
-   without either the DSE still runs but nothing persists.
+   without either the DSE still runs but nothing persists.  The plan is
+   derived for the ``(data=n, model=1)`` mesh of the devices present and
+   applied on that mesh.
 2. **Continuous batching** (:class:`repro.launch.scheduler
    .ContinuousBatcher`): a request queue drained through a fixed-width
    decode batch with per-step admit/evict and shape-bucketed batched
@@ -31,21 +33,25 @@ from __future__ import annotations
 import argparse
 import os
 import time
+from dataclasses import dataclass
 
 import jax
 import numpy as np
+from jax.sharding import Mesh
 
 from ..configs import get_config, list_archs
-from ..configs.base import ShapeSpec
-from ..core import (SINGLE_POD, MeshSpec, PlanCache, PlanKey, analyze_plan,
+from ..configs.base import ArchConfig, ShapeSpec
+from ..core import (MeshSpec, PlanCache, PlanKey, analyze_plan,
                     build_lm_graph, fetch_or_optimize, shape_bucket)
 from ..models.lm import LM
+from .compile_cache import enable_compile_cache
+from .mesh import host_mesh_and_spec
 from .scheduler import ContinuousBatcher, Request, prefill_bucket, run_static
 
 
 def fetch_plan(cfg, *, slots: int, s_max: int,
                cache_root: str | os.PathLike | None,
-               mesh: MeshSpec = SINGLE_POD,
+               mesh: MeshSpec,
                cache: PlanCache | None = None,
                optimize_kwargs: dict | None = None):
     """Serving-side compile: cache hit → warm re-DSE → cold DSE.
@@ -94,7 +100,7 @@ def _static_requests(trace: list[dict]) -> list[Request]:
             for i, t in enumerate(trace)]
 
 
-def main(argv=None) -> dict:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m", choices=list_archs())
     ap.add_argument("--smoke", action="store_true")
@@ -119,9 +125,28 @@ def main(argv=None) -> dict:
         "(default: $REPRO_PLAN_CACHE; unset = no persistence)")
     ap.add_argument("--no-plan", action="store_true",
                     help="skip the DSE/plan fetch entirely")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+@dataclass
+class Server:
+    """Everything one serving invocation runs on: the model with its
+    plan applied on the mesh that runs, its parameters, and the trace."""
+    cfg: ArchConfig
+    mesh: Mesh
+    plan_info: dict
+    lm: LM
+    params: dict
+    trace: list[dict]
+    s_max: int
+
+
+def build(args: argparse.Namespace) -> Server:
+    """Plan fetch + lint, model and parameter init, request trace.  The
+    plan is derived for the mesh that runs and applied on it; parameters
+    are made under that mesh."""
     cfg = get_config(args.arch, smoke=args.smoke)
+    mesh, mspec = host_mesh_and_spec()
     pl_lo, pl_hi = args.prompt_len_range
     g_lo, g_hi = args.gen_range
     s_max = prefill_bucket(pl_hi, 16) + g_hi
@@ -129,7 +154,7 @@ def main(argv=None) -> dict:
     plan, plan_info = (None, {"source": "skipped", "fetch_ms": 0.0}) \
         if args.no_plan else fetch_plan(
             cfg, slots=args.slots, s_max=s_max,
-            cache_root=args.plan_cache)
+            cache_root=args.plan_cache, mesh=mspec)
     if plan_info["source"] != "skipped":
         print(f"[serve] plan: {plan_info['source']} in "
               f"{plan_info['fetch_ms']:.1f} ms "
@@ -141,7 +166,11 @@ def main(argv=None) -> dict:
         # on a hazardous plan unannounced.  Informational, not fatal:
         # the endpoint owner decides (the --strict lane is
         # ``python -m repro.lint``).
-        lint = analyze_plan(plan, SINGLE_POD)
+        report = plan_info.pop("report")
+        if report is not None:
+            plan_info["degradations"] = [str(d)
+                                         for d in report.degradations]
+        lint = analyze_plan(plan, mspec)
         plan_info["lint"] = {"ok": lint.ok,
                              "issues": [str(i) for i in lint.issues]}
         print(f"[serve] lint: {lint.summary()}")
@@ -150,49 +179,61 @@ def main(argv=None) -> dict:
     # trace never share a key, and sampling streams are derived
     # per-request inside the scheduler.
     k_init, _k_reserved = jax.random.split(jax.random.PRNGKey(args.seed))
-    lm = LM(cfg, plan=plan, remat="none")
-    params, _ = lm.init(k_init)
+    lm = LM(cfg, plan=plan, mesh=mesh, remat="none")
+    with jax.set_mesh(mesh):
+        params, _ = lm.init(k_init)
     trace = make_trace(cfg, args.requests, seed=args.seed,
                        prompt_len_range=(pl_lo, pl_hi),
                        gen_range=(g_lo, g_hi),
                        temperature=args.temperature)
+    return Server(cfg, mesh, plan_info, lm, params, trace, s_max)
 
-    is_moe = any(ffn == "moe" for _, ffn in cfg.layer_kinds())
-    metrics: dict = {"arch": args.arch, "plan": {
-        k: v for k, v in plan_info.items() if k != "report"}}
+
+def main(argv=None) -> dict:
+    args = parse_args(argv)
+    enable_compile_cache()
+    srv = build(args)
+    lm, params, trace, s_max = srv.lm, srv.params, srv.trace, srv.s_max
+
+    is_moe = any(ffn == "moe" for _, ffn in srv.cfg.layer_kinds())
+    metrics: dict = {"arch": args.arch, "plan": srv.plan_info}
     if is_moe:
         print(f"[serve] {args.arch} has MoE layers — static path only "
               "(expert capacity couples batch rows)")
-    else:
-        def run_once():
-            b = ContinuousBatcher(lm, params, slots=args.slots,
-                                  s_max=s_max, seed=args.seed,
-                                  eos_id=args.eos_id)
-            for t in trace:
-                b.submit(t["prompt"], t["max_new"],
-                         prompt_len=t["prompt_len"],
-                         temperature=t["temperature"])
-            return b.run()
+    with jax.set_mesh(srv.mesh):
+        if not is_moe:
+            def run_once():
+                b = ContinuousBatcher(lm, params, slots=args.slots,
+                                      s_max=s_max, seed=args.seed,
+                                      eos_id=args.eos_id)
+                for t in trace:
+                    b.submit(t["prompt"], t["max_new"],
+                             prompt_len=t["prompt_len"],
+                             temperature=t["temperature"])
+                return b.run()
 
-        for _ in range(args.warmup):
-            run_once()
-        rep = run_once()
-        metrics["continuous"] = rep.to_dict()
-        print(f"[serve] continuous: {rep.generated} tokens / "
-              f"{len(rep.requests)} requests in {rep.wall_s:.2f}s "
-              f"({rep.to_dict()['tok_per_s']:.0f} tok/s, occupancy "
-              f"{rep.occupancy:.2f}, p50 "
-              f"{rep.to_dict()['latency_p50_s'] * 1e3:.0f} ms, p99 "
-              f"{rep.to_dict()['latency_p99_s'] * 1e3:.0f} ms)")
+            for _ in range(args.warmup):
+                run_once()
+            rep = run_once()
+            metrics["continuous"] = rep.to_dict()
+            metrics["outputs"] = [r.out for r in sorted(
+                rep.requests, key=lambda r: r.rid)]
+            print(f"[serve] continuous: {rep.generated} tokens / "
+                  f"{len(rep.requests)} requests in {rep.wall_s:.2f}s "
+                  f"({rep.to_dict()['tok_per_s']:.0f} tok/s, occupancy "
+                  f"{rep.occupancy:.2f}, p50 "
+                  f"{rep.to_dict()['latency_p50_s'] * 1e3:.0f} ms, p99 "
+                  f"{rep.to_dict()['latency_p99_s'] * 1e3:.0f} ms)")
 
+        if args.static or is_moe:
+            for _ in range(args.warmup):
+                run_static(lm, params, _static_requests(trace),
+                           seed=args.seed, s_max=s_max, slots=args.slots,
+                           eos_id=args.eos_id)
+            srep = run_static(lm, params, _static_requests(trace),
+                              seed=args.seed, s_max=s_max,
+                              slots=args.slots, eos_id=args.eos_id)
     if args.static or is_moe:
-        for _ in range(args.warmup):
-            run_static(lm, params, _static_requests(trace),
-                       seed=args.seed, s_max=s_max, slots=args.slots,
-                       eos_id=args.eos_id)
-        srep = run_static(lm, params, _static_requests(trace),
-                          seed=args.seed, s_max=s_max, slots=args.slots,
-                          eos_id=args.eos_id)
         metrics["static"] = srep.to_dict()
         print(f"[serve] static:     {srep.generated} tokens / "
               f"{len(srep.requests)} requests in {srep.wall_s:.2f}s "

@@ -10,8 +10,9 @@ On this CPU container run the reduced configs::
     PYTHONPATH=src python -m repro.launch.train --arch smollm-135m \
         --smoke --steps 50 --batch 8 --seq 64
 
-On a real pod the same driver runs the full config against
-``make_production_mesh()`` — nothing in the loop is CPU-specific.
+On an accelerator the same driver runs the full config (drop
+``--smoke``): the plan is derived for, and applied on, the
+``(data=n_devices, model=1)`` mesh of the devices present.
 """
 from __future__ import annotations
 
@@ -22,27 +23,28 @@ from pathlib import Path
 import jax
 import numpy as np
 
-from ..configs import SHAPES, get_config, list_archs
+from ..configs import get_config, list_archs
 from ..configs.base import ShapeSpec
-from ..core import build_lm_graph, optimize
-from ..core.estimator import MeshSpec
+from ..core import analyze_plan, build_lm_graph, optimize
 from ..data import ShardedLoader, SyntheticCorpus
 from ..distributed import CheckpointManager, StragglerMonitor
 from ..models.lm import LM
 from ..optim import AdamW, cosine_schedule
-from .mesh import make_host_mesh, set_mesh
+from .compile_cache import enable_compile_cache
+from .mesh import host_mesh_and_spec
 
 
 def build(args):
     cfg = get_config(args.arch, smoke=args.smoke)
     shape = ShapeSpec("cli", args.seq, args.batch, "train")
 
-    n_dev = len(jax.devices())
-    mesh = make_host_mesh((n_dev, 1))
-    mspec = MeshSpec((("data", n_dev), ("model", 1)))
-
+    mesh, mspec = host_mesh_and_spec()
     g = build_lm_graph(cfg, shape)
     sched, plan, report = optimize(g, mspec, fsdp=args.fsdp)
+    lint = analyze_plan(plan, mspec)
+    plan_info = {"degradations": [str(d) for d in report.degradations],
+                 "lint": {"ok": lint.ok,
+                          "issues": [str(i) for i in lint.issues]}}
     lm = LM(cfg, plan=plan, remat=args.remat)
     opt = AdamW(lr=args.lr, moment_dtype=cfg.opt_moment_dtype)
     lr_fn = cosine_schedule(1.0, warmup=max(args.steps // 20, 1),
@@ -55,7 +57,7 @@ def build(args):
                                        lr_scale=lr_fn(step))
         return params, opt_state, metrics
 
-    return cfg, shape, mesh, plan, lm, opt, jax.jit(
+    return cfg, shape, mesh, plan_info, lm, opt, jax.jit(
         train_step, donate_argnums=(0, 1))
 
 
@@ -74,8 +76,9 @@ def main(argv=None) -> dict:
     ap.add_argument("--simulate-preemption-at", type=int, default=-1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
-    cfg, shape, mesh, plan, lm, opt, step_fn = build(args)
+    cfg, shape, mesh, plan_info, lm, opt, step_fn = build(args)
     corpus = SyntheticCorpus(cfg.vocab, seed=args.seed)
     loader = ShardedLoader(corpus, args.batch, args.seq)
     ckpt = CheckpointManager(args.ckpt_dir)
@@ -95,12 +98,13 @@ def main(argv=None) -> dict:
         print(f"[train] resumed from step {latest}")
 
     losses = []
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for step in range(start, args.steps):
             if step == args.simulate_preemption_at and not restored:
                 print(f"[train] simulated preemption at step {step}")
                 ckpt.wait()
-                return {"preempted_at": step, "losses": losses}
+                return {"preempted_at": step, "losses": losses,
+                        "plan": plan_info}
             t0 = time.perf_counter()
             batch = {k: jax.device_put(v)
                      for k, v in loader.batch_at(step).items()}
@@ -117,7 +121,7 @@ def main(argv=None) -> dict:
                 ckpt.save(step + 1, {"params": params, "opt": opt_state})
     ckpt.wait()
     return {"final_loss": losses[-1] if losses else None,
-            "losses": losses, "resumed_from": start}
+            "losses": losses, "resumed_from": start, "plan": plan_info}
 
 
 if __name__ == "__main__":

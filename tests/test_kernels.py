@@ -1,5 +1,6 @@
 """Per-kernel correctness: interpret-mode pallas_call vs pure-jnp oracle,
-swept over shapes / dtypes / block sizes (deliverable c)."""
+swept over shapes / dtypes / block sizes (deliverable c).  On the CPU
+backend ``interpret=None`` resolves to the interpreter."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -120,6 +121,62 @@ def test_moe_gmm_kernel(dtype, E, C, D, F, cb, fb, db):
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
         **_tol(dtype))
+
+
+# -- regressions for the TPU-compilable layouts --------------------------------
+
+def test_moe_gmm_unequal_groups_scalar_prefetch():
+    """group_sizes is scalar-prefetched: each expert must mask with its
+    own count, across several row blocks per expert."""
+    from repro.kernels.moe_gmm.kernel import moe_gmm
+    E, C, D, F = 4, 64, 32, 64
+    x = jnp.asarray(RNG.normal(size=(E, C, D)), jnp.float32)
+    w = jnp.asarray(RNG.normal(size=(E, D, F)) * 0.1, jnp.float32)
+    gs = jnp.asarray([0, 5, 64, 37], jnp.int32)
+    got = moe_gmm(x, w, gs, c_block=16, f_block=32, d_block=16)
+    want = moe_gmm_ref(x, w, gs)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
+    assert not np.asarray(got)[0].any() and not np.asarray(got)[1, 5:].any()
+
+
+def test_mlstm_gate_rows_per_head():
+    """Gates are (BH, 1, S) blocks: every batch·head row must read its
+    own gates (rows differ strongly), across several chunks."""
+    from repro.kernels.mlstm_chunk.kernel import mlstm_chunk
+    BH, S, Dh = 6, 48, 8
+    q, k, v = (jnp.asarray(RNG.normal(size=(BH, S, Dh)), jnp.float32)
+               for _ in range(3))
+    row = jnp.arange(BH, dtype=jnp.float32)[:, None]
+    i_pre = jnp.asarray(RNG.normal(size=(BH, S)), jnp.float32) + row - 3
+    f_pre = jnp.asarray(RNG.normal(size=(BH, S)), jnp.float32) + 2 - row
+    got = mlstm_chunk(q, k, v, i_pre, f_pre, chunk=16)
+    want = mlstm_ref(q, k, v, i_pre, f_pre)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-3, atol=2e-3)
+
+
+def test_ssd_scan_transposed_state_blocks():
+    """Transposed state and per-step ref indexing: several batch rows,
+    channel blocks and chunks, with the state carried across chunks."""
+    from repro.kernels.ssd_scan.kernel import ssd_scan
+    B, S, Din, N = 3, 40, 24, 5
+    x = jnp.asarray(RNG.normal(size=(B, S, Din)), jnp.float32)
+    dt = jnp.asarray(RNG.uniform(0.01, 0.3, size=(B, S, Din)), jnp.float32)
+    A = -jnp.asarray(RNG.uniform(0.2, 2.0, size=(Din, N)), jnp.float32)
+    Bm = jnp.asarray(RNG.normal(size=(B, S, N)), jnp.float32)
+    Cm = jnp.asarray(RNG.normal(size=(B, S, N)), jnp.float32)
+    got = ssd_scan(x, dt, A, Bm, Cm, chunk=8, d_block=8)
+    want = ssd_scan_ref(x, dt, A, Bm, Cm)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_interpret_resolves_by_backend():
+    from repro.kernels import resolve_interpret
+    assert resolve_interpret(None) == (jax.default_backend() == "cpu")
+    assert resolve_interpret(False) is False
+    assert resolve_interpret(True) is True
 
 
 # -- rmsnorm --------------------------------------------------------------------

@@ -4,7 +4,8 @@ device; XLA locks the device count at first jax import).
 Covers: (a) a reduced-mesh dry-run — lower+compile the real train step on
 a (4,2) mesh with a HIDA plan, collectives present; (b) the GPipe
 pipeline runtime over a 4-way stage axis vs the sequential oracle;
-(c) shard_map EP MoE vs the global oracle on a (2,2) mesh."""
+(c) shard_map EP MoE vs the global oracle on a (2,2) mesh; (d)
+``chip_smoke.py --chips 4``'s phase at smoke size on a (2,2) mesh."""
 import os
 import subprocess
 import sys
@@ -36,15 +37,15 @@ def test_dryrun_reduced_mesh_compiles():
         from repro.core import MeshSpec, build_lm_graph, optimize
         from repro.launch.steps import build_train_step
         from repro.launch.hlo_analysis import collective_bytes
-        from repro.launch.mesh import set_mesh
+        from repro.launch.mesh import make_mesh
 
         cfg = get_config("smollm-135m")
         shape = ShapeSpec("t", 512, 16, "train")
         mspec = MeshSpec((("data", 4), ("model", 2)))
         g = build_lm_graph(cfg, shape)
         sched, plan, rep = optimize(g, mspec, training=True)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
-        with set_mesh(mesh):
+        mesh = make_mesh((4, 2), ("data", "model"))
+        with jax.set_mesh(mesh):
             step = build_train_step(cfg, shape, mesh, plan)
             compiled = step.fn.lower(*step.abstract_inputs).compile()
         stats = collective_bytes(compiled.as_text())
@@ -60,9 +61,10 @@ def test_gpipe_pipeline_matches_sequential():
     out = _run(4, """
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.pipeline import PipelineConfig, gpipe
+        from repro.launch.mesh import make_mesh
 
         S, M, B, D = 4, 6, 2, 8
-        mesh = jax.make_mesh((S,), ("pod",))
+        mesh = make_mesh((S,), ("pod",))
         rng = np.random.default_rng(0)
         Ws = jnp.asarray(rng.normal(size=(S, D, D)) * 0.3, jnp.float32)
         mb = jnp.asarray(rng.normal(size=(M, B, D)), jnp.float32)
@@ -88,7 +90,7 @@ def test_ep_moe_matches_global():
     out = _run(4, """
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config
-        from repro.launch.mesh import set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models.moe import moe_ffn, moe_ffn_ep
         from repro.models.layers import ParamBuilder
         from repro.models.moe import init_moe
@@ -103,9 +105,9 @@ def test_ep_moe_matches_global():
         B, S, D = 4, 8, cfg.d_model
         x = jax.random.normal(jax.random.PRNGKey(1), (B, S, D),
                               jnp.float32).astype(jnp.bfloat16)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         ref, aux_ref = moe_ffn(x, p, cfg, lambda t, d, s=None: t)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             got, aux = jax.jit(lambda x, p: moe_ffn_ep(
                 x, p, cfg, ("data",), ("model",), (), mesh))(x, p)
         np.testing.assert_allclose(
@@ -114,3 +116,15 @@ def test_ep_moe_matches_global():
         print("OK ep moe", float(aux.dropped_fraction))
     """)
     assert "OK ep moe" in out
+
+
+def test_chip_smoke_multichip_phase():
+    out = _run(4, f"""
+        import sys
+        sys.path.insert(0, {REPO!r})
+        import chip_smoke
+        problems = chip_smoke.multichip_phase(0, 8, 64, smoke=True)
+        assert problems == [], problems
+        print("OK chips4")
+    """)
+    assert "OK chips4" in out

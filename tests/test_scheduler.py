@@ -20,8 +20,8 @@ jax = pytest.importorskip("jax")
 
 from repro.configs import get_config                              # noqa: E402
 from repro.launch.scheduler import (ContinuousBatcher, Request,   # noqa: E402
-                                    decode_offline, prefill_bucket,
-                                    run_static)
+                                    decode_offline, greedy_margins,
+                                    prefill_bucket, run_static)
 
 S_MAX = 96
 
@@ -74,6 +74,21 @@ def test_streamed_tokens_match_offline(served):
         assert r.finish == "length" and len(r.out) == r.max_new
         ref = decode_offline(lm, params, r, seed=0, s_max=S_MAX)
         assert r.out == ref, f"rid {r.rid}: {r.out} != {ref}"
+
+
+def test_greedy_margins_zero_on_greedy_stream(served):
+    """The near-tie measure behind the chip smoke check: an offline greedy
+    stream sits 0 ulps below every argmax; a swapped token does not."""
+    cfg, lm, params = served
+    prompt, gen, _ = _trace(cfg)[1]
+    req = Request(rid=1, prompt_len=len(prompt), max_new=gen, prompt=prompt)
+    toks = decode_offline(lm, params, req, seed=0, s_max=S_MAX)
+    m = greedy_margins(lm, params, req, toks, s_max=S_MAX)
+    assert m.shape == (gen,) and not m.any()
+    bad = list(toks)
+    bad[2] = (bad[2] + 1) % cfg.vocab
+    m = greedy_margins(lm, params, req, bad, s_max=S_MAX)
+    assert m[2] > 0 and not m[:2].any()
 
 
 def test_slot_reuse_and_occupancy(served):

@@ -222,7 +222,7 @@ def test_grad_accumulation_matches_full_batch():
     from repro.configs import get_config
     from repro.configs.base import ShapeSpec
     from repro.core import MeshSpec, build_lm_graph, optimize
-    from repro.launch.mesh import make_host_mesh, set_mesh
+    from repro.launch.mesh import make_host_mesh
     from repro.launch.steps import build_train_step
     from repro.data import SyntheticCorpus
 
@@ -237,7 +237,7 @@ def test_grad_accumulation_matches_full_batch():
              corpus.batch(0, 0, 4, 16).items()}
 
     outs = {}
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for accum in (1, 2):
             step = build_train_step(cfg, shape, mesh, plan, remat="none",
                                     accum_steps=accum)
