@@ -1,0 +1,6 @@
+"""Device idle share of the traced chat window, while serving (%)."""
+from readers import idle_share
+
+
+def read(facts):
+    return idle_share(facts)
