@@ -9,8 +9,8 @@ Two arm families, one JSON (``BENCH_serve.json``):
   batching exists for: short requests finish and their slots are
   refilled while long ones keep decoding).  One un-timed warmup pass
   absorbs jit compiles, so the numbers are what a long-lived endpoint
-  serves at.  Reported: total and decode-only tok/s, p50/p99 request
-  latency, slot occupancy, and the continuous/static ratio.
+  serves at.  Reported: total and decode-only tok/s, slot occupancy,
+  and the continuous/static ratio.
 * ``plan_cache/<arch>`` — the compile-side tiers on every zoo config
   (full, non-smoke): cold DSE wall, cache-hit fetch time (fresh
   :class:`PlanCache` instance, so the disk tier + static re-verify are
@@ -87,8 +87,6 @@ def _bench_serve_arm(arch: str, repeats: int = 3) -> dict:
         "decode_tok_per_s": c["decode_tok_per_s"],
         "static_tok_per_s": s["tok_per_s"],
         "ratio_vs_static": best["continuous_vs_static"],
-        "latency_p50_s": c["latency_p50_s"],
-        "latency_p99_s": c["latency_p99_s"],
         "occupancy": c["occupancy"],
         "requests": c["requests"],
         "generated": c["generated"],
@@ -154,8 +152,6 @@ def run(report, fast: bool = False) -> dict:
                    derived=f"tok_per_s={r['tok_per_s']:.0f}"
                            f"|static={r['static_tok_per_s']:.0f}"
                            f"|ratio={r['ratio_vs_static']:.2f}"
-                           f"|p50_ms={r['latency_p50_s'] * 1e3:.0f}"
-                           f"|p99_ms={r['latency_p99_s'] * 1e3:.0f}"
                            f"|occ={r['occupancy']:.2f}")
     archs = list_archs()
     if fast:
@@ -220,9 +216,7 @@ def compare(results: dict, baseline: dict, threshold: float,
             ratio = (old["tok_per_s"] / new["tok_per_s"]
                      if new["tok_per_s"] else float("inf"))
             print(f"{arm}: {old['tok_per_s']:.0f} -> "
-                  f"{new['tok_per_s']:.0f} tok/s, p99 "
-                  f"{old['latency_p99_s'] * 1e3:.0f} -> "
-                  f"{new['latency_p99_s'] * 1e3:.0f} ms")
+                  f"{new['tok_per_s']:.0f} tok/s")
             if ratio > threshold:
                 failures.append(
                     f"{arm}: throughput dropped to {new['tok_per_s']:.0f} "

@@ -7,7 +7,6 @@ Prints ``name,us_per_call,derived`` CSV.  Suites:
 * ``models``          — Table 8 (the 10-arch zoo, HIDA vs naive)
 * ``ablation_iaca``   — Fig. 11 (IA+CA vs IA vs CA vs naive sweep)
 * ``ablation_scale``  — Fig. 10 (parallel factor × tile size)
-* ``roofline``        — §Roofline rows from dry-run artifacts (if present)
 * ``train_smoke``     — real measured CPU training throughput (smoke cfg)
 * ``compile_time``    — ``optimize()`` wall time per config (the compiler's
   own perf trajectory; also emits ``BENCH_compile_time.json``).  Run as
@@ -95,7 +94,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--suite", default="all",
                     choices=("all", "case_study", "polybench", "models",
-                             "ablation_iaca", "ablation_scale", "roofline",
+                             "ablation_iaca", "ablation_scale",
                              "train_smoke", "compile_time", "serve",
                              "lint"))
     ap.add_argument("--fast", action="store_true",
@@ -123,9 +122,6 @@ def main() -> None:
     if want("ablation_scale"):
         from .bench_ablation_scale import run as r
         r(report, factors=(16, 256) if args.fast else (4, 16, 64, 256))
-    if want("roofline"):
-        from .roofline import run as r
-        r(report)
     if want("train_smoke"):
         bench_train_smoke(report)
     if want("compile_time"):

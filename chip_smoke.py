@@ -142,8 +142,7 @@ def serve_phase(argv: list[str], n_oracle: int) -> list[str]:
     c = m["continuous"]
     print(f"[smoke] serve (smoke figure, not a benchmark): "
           f"{c['generated']} tokens, {c['requests']} requests, "
-          f"{c['tok_per_s']:.1f} tok/s, latency p50 "
-          f"{c['latency_p50_s']:.3f} s p99 {c['latency_p99_s']:.3f} s, "
+          f"{c['tok_per_s']:.1f} tok/s, "
           f"wall {c['wall_s']:.2f} s", flush=True)
 
     # The oracle side: same seed → same plan, parameters and trace.

@@ -19,6 +19,7 @@ from queue import Queue
 from typing import Iterator
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 @dataclass
@@ -78,7 +79,9 @@ class ShardedLoader:
         t.start()
         try:
             while True:
-                yield q.get()
+                with TraceAnnotation("data.next_batch"):
+                    batch = q.get()
+                yield batch
         finally:
             stop.set()
 

@@ -10,8 +10,7 @@ with every input a ShapeDtypeStruct (zero allocation).  Captures:
 * collective bytes parsed from the post-SPMD HLO,
 * HIDA-OPT pass reports + the derived plan.
 
-Artifacts land in ``experiments/dryrun/<arch>__<shape>__<mesh>.json``;
-``benchmarks/roofline.py`` renders the §Roofline table from them.
+Artifacts land in ``experiments/dryrun/<arch>__<shape>__<mesh>.json``.
 
 Usage:
     JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.dryrun \

@@ -37,6 +37,7 @@ reproducible from ``seed`` alone.
 """
 from __future__ import annotations
 
+import functools
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -44,6 +45,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 #: Per-model jit memo: ``jax.jit(lm.decode_step)`` binds a *new*
 #: function object every time, so naively jitting in each batcher (or
@@ -123,15 +125,6 @@ class Request:
     t_done: float = 0.0
     finish: str = ""        # "eos" | "length"
 
-    @property
-    def latency_s(self) -> float:
-        return self.t_done - self.t_submit
-
-    @property
-    def ttft_s(self) -> float:
-        """Submit → first generated token."""
-        return self.t_first - self.t_submit
-
 
 @dataclass
 class ServeReport:
@@ -152,24 +145,13 @@ class ServeReport:
     def decode_tok_per_s(self) -> float:
         return self.generated / self.decode_s if self.decode_s else 0.0
 
-    def latency_percentiles(self) -> dict[str, float]:
-        lats = sorted(r.latency_s for r in self.requests)
-        if not lats:
-            return {"p50": 0.0, "p99": 0.0}
-        def pct(p: float) -> float:
-            i = min(len(lats) - 1, int(round(p / 100 * (len(lats) - 1))))
-            return lats[i]
-        return {"p50": pct(50), "p99": pct(99)}
-
     def to_dict(self) -> dict:
-        lat = self.latency_percentiles()
         return {"requests": len(self.requests),
                 "generated": self.generated, "steps": self.steps,
                 "tok_per_s": self.tok_per_s,
                 "decode_tok_per_s": self.decode_tok_per_s,
                 "prefill_s": self.prefill_s, "decode_s": self.decode_s,
                 "wall_s": self.wall_s, "occupancy": self.occupancy,
-                "latency_p50_s": lat["p50"], "latency_p99_s": lat["p99"],
                 "slots": self.slots}
 
 
@@ -304,16 +286,17 @@ class ContinuousBatcher:
             # install: batch axis of every leaf is 0, except inside
             # stacked (scanned) layer groups where axis 0 is layers.
             out = {}
-            for gi, (_pattern, repeats) in enumerate(groups):
-                ax = 1 if repeats > 1 else 0
-                g = f"group{gi}"
+            with jax.named_scope("prefill_install"):
+                for gi, (_pattern, repeats) in enumerate(groups):
+                    ax = 1 if repeats > 1 else 0
+                    g = f"group{gi}"
 
-                def ins(b, s, ax=ax):
-                    if ax == 0:
-                        return b.at[slot_vec].set(s)
-                    return b.at[:, slot_vec].set(s)
+                    def ins(b, s, ax=ax):
+                        if ax == 0:
+                            return b.at[slot_vec].set(s)
+                        return b.at[:, slot_vec].set(s)
 
-                out[g] = jax.tree.map(ins, big[g], small[g])
+                    out[g] = jax.tree.map(ins, big[g], small[g])
             # logits: (bucket, k, vocab) → each request's row at its
             # own last prompt position.
             last = jnp.take_along_axis(
@@ -370,21 +353,23 @@ class ContinuousBatcher:
         self.caches, last = fn(
             self.params, xs, jnp.asarray(lengths), self.caches,
             jnp.asarray(slot_vec), self._zero_cache(k), img)
-        last_np = np.asarray(last)
+        with TraceAnnotation("serve.prefill.wait"):
+            last_np = np.asarray(last)
         t_first = time.perf_counter()
-        for i, (slot, req) in enumerate(pairs):
-            tok = _sample(last_np[i], keys[i], req.prompt_len - 1,
-                          req.temperature)
-            req.out.append(tok)
-            req.t_first = t_first
-            self.pos[slot] = req.prompt_len
-            self.active[slot] = True
-            self.tokens[slot, 0] = tok
-            self.slot_req[slot] = req
-            self._slot_key[slot] = keys[i]
-            if self._slot_img is not None:
-                self._slot_img[slot] = np.asarray(img[i], np.float32)
-            self._maybe_finish(slot, tok)
+        with TraceAnnotation("serve.sample"):
+            for i, (slot, req) in enumerate(pairs):
+                tok = _sample(last_np[i], keys[i], req.prompt_len - 1,
+                              req.temperature)
+                req.out.append(tok)
+                req.t_first = t_first
+                self.pos[slot] = req.prompt_len
+                self.active[slot] = True
+                self.tokens[slot, 0] = tok
+                self.slot_req[slot] = req
+                self._slot_key[slot] = keys[i]
+                if self._slot_img is not None:
+                    self._slot_img[slot] = np.asarray(img[i], np.float32)
+                self._maybe_finish(slot, tok)
 
     def _evict(self, slot: int, finish: str) -> None:
         req = self.slot_req[slot]
@@ -422,10 +407,14 @@ class ContinuousBatcher:
             batch["img_embeds"] = jnp.asarray(self._slot_img, jnp.bfloat16)
         return batch
 
+    @functools.partial(jax.profiler.annotate_function, name="serve.run")
     def run(self, max_steps: int | None = None) -> ServeReport:
         """Drain the queue: admit → step → sample/evict until every
         submitted request has finished.  Returns the serving report;
-        per-request tokens live on the :class:`Request` objects."""
+        per-request tokens live on the :class:`Request` objects.
+
+        Profiler spans (``serve.*``; free while no trace is running) name
+        the host's part of each step on the device trace's clock."""
         rep = ServeReport(slots=self.slots)
         occ_sum = 0.0
         t_start = time.perf_counter()
@@ -447,30 +436,35 @@ class ContinuousBatcher:
                         groups.setdefault(b, []).append((slot, req))
                         rep.requests.append(req)
                 for b, pairs in sorted(groups.items()):
-                    self._admit_group(pairs, b)
+                    with TraceAnnotation("serve.admit", bucket=b,
+                                         width=len(pairs)):
+                        self._admit_group(pairs, b)
                 if groups:
                     rep.prefill_s += time.perf_counter() - t0
             if not self.active.any():
                 continue    # every admitted request finished at token 0
             # one decode step over the whole batch
             t0 = time.perf_counter()
-            batch = self._decode_batch()
-            logits, self.caches = self._step(self.params, batch,
-                                             self.caches)
-            logits_np = np.asarray(logits[:, -1])
+            with TraceAnnotation("serve.decode.dispatch"):
+                batch = self._decode_batch()
+                logits, self.caches = self._step(self.params, batch,
+                                                 self.caches)
+            with TraceAnnotation("serve.decode.wait"):
+                logits_np = np.asarray(logits[:, -1])
             rep.decode_s += time.perf_counter() - t0
             rep.steps += 1
             occ_sum += float(self.active.sum()) / self.slots
-            for slot in range(self.slots):
-                if not self.active[slot]:
-                    continue
-                req = self.slot_req[slot]
-                tok = _sample(logits_np[slot], self._slot_key[slot],
-                              int(self.pos[slot]), req.temperature)
-                req.out.append(tok)
-                self.pos[slot] += 1
-                self.tokens[slot, 0] = tok
-                self._maybe_finish(slot, tok)
+            with TraceAnnotation("serve.sample"):
+                for slot in range(self.slots):
+                    if not self.active[slot]:
+                        continue
+                    req = self.slot_req[slot]
+                    tok = _sample(logits_np[slot], self._slot_key[slot],
+                                  int(self.pos[slot]), req.temperature)
+                    req.out.append(tok)
+                    self.pos[slot] += 1
+                    self.tokens[slot, 0] = tok
+                    self._maybe_finish(slot, tok)
             if rep.steps >= budget:
                 for slot in range(self.slots):
                     if self.active[slot]:

@@ -221,9 +221,7 @@ def main(argv=None) -> dict:
             print(f"[serve] continuous: {rep.generated} tokens / "
                   f"{len(rep.requests)} requests in {rep.wall_s:.2f}s "
                   f"({rep.to_dict()['tok_per_s']:.0f} tok/s, occupancy "
-                  f"{rep.occupancy:.2f}, p50 "
-                  f"{rep.to_dict()['latency_p50_s'] * 1e3:.0f} ms, p99 "
-                  f"{rep.to_dict()['latency_p99_s'] * 1e3:.0f} ms)")
+                  f"{rep.occupancy:.2f})")
 
         if args.static or is_moe:
             for _ in range(args.warmup):

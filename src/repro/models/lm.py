@@ -39,6 +39,17 @@ AUX_LB_WEIGHT = 0.01
 AUX_Z_WEIGHT = 1e-3
 MTP_WEIGHT = 0.3
 
+#: The ``jax.named_scope`` names of the compiled programs, by layer: the
+#: mixer sublayer by kind (``attn`` also for cross-attention and MLA),
+#: the FFN, the layer scan's own work (each layer's slice of the stacked
+#: weights and caches, the stacked write of its new cache), the cache
+#: gate, the head, the loss, the serving prefill's slot install and the
+#: optimizer update.  They reach each optimised HLO instruction's
+#: ``metadata={op_name=...}``, where trace readers find the innermost one
+#: to attribute device time to a layer.
+SCOPES = ("embed", "attn", "mamba", "mlstm", "slstm", "ffn", "layer_scan",
+          "cache_gate", "head", "loss", "prefill_install", "optimizer")
+
 
 def _noop_constrain(x, dims, site=None):
     return x
@@ -135,51 +146,54 @@ class LM:
         cfg = self.cfg
         c = self.constrain
         aux = MoEAux(jnp.zeros(()), jnp.zeros(()), jnp.zeros(()))
-        x = apply_norm(cfg.norm, resid, bp["norm1"])
         new_cache = cache
-        if mix in ("attn", "xattn"):
-            kv_x = img if mix == "xattn" else None
-            if cfg.mla is not None:
-                out, kvc = mla_attention(x, bp["mix"], cfg, positions, c,
-                                         cache=cache)
-            else:
-                out, kvc = gqa_attention(
-                    x, bp["mix"], cfg, positions, c, cache=cache,
-                    kv_x=kv_x,
-                    use_kernels=self.use_kernels and cache is None)
-            new_cache = kvc if cache is not None else None
-        elif mix == "mamba":
-            if cache is not None:
-                state, carry = cache
-                out, state, carry = mamba_block(
-                    x, bp["mix"], cfg, c, state=state, conv_carry=carry)
-                new_cache = (state, carry)
-            else:
-                out = mamba_block(x, bp["mix"], cfg, c,
-                                  use_kernels=self.use_kernels)
-        elif mix == "mlstm":
-            if cache is not None:
-                out, new_cache = mlstm_block(x, bp["mix"], cfg, c,
-                                             state=cache)
-            else:
-                out = mlstm_block(x, bp["mix"], cfg, c,
-                                  use_kernels=self.use_kernels)
-        elif mix == "slstm":
-            if cache is not None:
-                out, new_cache = slstm_block(x, bp["mix"], cfg, c,
-                                             state=cache)
-            else:
-                out = slstm_block(x, bp["mix"], cfg, c)
-        resid = resid + out
+        with jax.named_scope("attn" if mix == "xattn" else mix):
+            x = apply_norm(cfg.norm, resid, bp["norm1"])
+            if mix in ("attn", "xattn"):
+                kv_x = img if mix == "xattn" else None
+                if cfg.mla is not None:
+                    out, kvc = mla_attention(x, bp["mix"], cfg, positions,
+                                             c, cache=cache)
+                else:
+                    out, kvc = gqa_attention(
+                        x, bp["mix"], cfg, positions, c, cache=cache,
+                        kv_x=kv_x,
+                        use_kernels=self.use_kernels and cache is None)
+                new_cache = kvc if cache is not None else None
+            elif mix == "mamba":
+                if cache is not None:
+                    state, carry = cache
+                    out, state, carry = mamba_block(
+                        x, bp["mix"], cfg, c, state=state, conv_carry=carry)
+                    new_cache = (state, carry)
+                else:
+                    out = mamba_block(x, bp["mix"], cfg, c,
+                                      use_kernels=self.use_kernels)
+            elif mix == "mlstm":
+                if cache is not None:
+                    out, new_cache = mlstm_block(x, bp["mix"], cfg, c,
+                                                 state=cache)
+                else:
+                    out = mlstm_block(x, bp["mix"], cfg, c,
+                                      use_kernels=self.use_kernels)
+            elif mix == "slstm":
+                if cache is not None:
+                    out, new_cache = slstm_block(x, bp["mix"], cfg, c,
+                                                 state=cache)
+                else:
+                    out = slstm_block(x, bp["mix"], cfg, c)
+            resid = resid + out
         resid = c(resid, ("batch", "seq", "d_model"), "residual")
 
-        if ffn == "dense":
-            x2 = apply_norm(cfg.norm, resid, bp["norm2"])
-            resid = resid + mlp(x2, bp["ffn"], c)
-        elif ffn == "moe":
-            x2 = apply_norm(cfg.norm, resid, bp["norm2"])
-            moe_out, aux = moe_ffn(x2, bp["ffn"], cfg, c, ep=self._ep())
-            resid = resid + moe_out
+        if ffn in ("dense", "moe"):
+            with jax.named_scope("ffn"):
+                x2 = apply_norm(cfg.norm, resid, bp["norm2"])
+                if ffn == "dense":
+                    resid = resid + mlp(x2, bp["ffn"], c)
+                else:
+                    moe_out, aux = moe_ffn(x2, bp["ffn"], cfg, c,
+                                           ep=self._ep())
+                    resid = resid + moe_out
         resid = c(resid, ("batch", "seq", "d_model"), "residual2")
         return resid, aux, new_cache
 
@@ -236,12 +250,14 @@ class LM:
                     body, policy=jax.checkpoint_policies
                     .dots_with_no_batch_dims_saveable)
             xs = (gparams, gcaches) if caches is not None else gparams
-            (resid, lb, zl), scanned_caches = jax.lax.scan(
-                body, (resid, lb, zl), xs)
+            with jax.named_scope("layer_scan"):
+                (resid, lb, zl), scanned_caches = jax.lax.scan(
+                    body, (resid, lb, zl), xs)
             if caches is not None:
                 new_caches[f"group{gi}"] = scanned_caches
         return resid, (lb, zl), new_caches
 
+    @jax.named_scope("embed")
     def _embed(self, params, batch):
         cfg = self.cfg
         c = self.constrain
@@ -255,6 +271,7 @@ class LM:
             img = batch["img_embeds"].astype(BF16)
         return resid, img
 
+    @jax.named_scope("head")
     def _head(self, params, resid):
         cfg = self.cfg
         x = apply_norm(cfg.norm, resid, params["final_norm"])
@@ -283,7 +300,8 @@ class LM:
         resid, img = self._embed(params, batch)
         resid, (lb, zl), _ = self._backbone(params, resid, positions, img)
         logits = self._head(params, resid)
-        loss = cross_entropy(logits, batch["labels"])
+        with jax.named_scope("loss"):
+            loss = cross_entropy(logits, batch["labels"])
         metrics = {"xent": loss, "aux_lb": lb, "aux_z": zl}
         if cfg.mtp:
             mtp_loss = self._mtp_loss(params, resid, batch, positions)
@@ -459,6 +477,7 @@ class LM:
         logits = self._head(params, resid)
         return logits, new_caches
 
+    @jax.named_scope("cache_gate")
     def _gate_caches(self, active, old, new):
         """Per-slot select between the stepped and the previous cache
         leaves.  The batch axis of every leaf is 0, except inside a

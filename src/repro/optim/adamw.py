@@ -47,6 +47,7 @@ class AdamW:
         return AdamWState(step, jax.tree.map(zeros, params),
                           jax.tree.map(zeros, params))
 
+    @jax.named_scope("optimizer")
     def update(self, grads, state: AdamWState, params,
                lr_scale: jax.Array | float = 1.0):
         """Returns (new_params, new_state).  Update math in f32; params

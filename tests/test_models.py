@@ -1,5 +1,7 @@
 """Model-zoo tests: per-arch smoke (deliverable f), decode-vs-parallel
 consistency for every sequence-mixer family, and sub-block oracles."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -72,6 +74,75 @@ def test_arch_smoke_decode_shapes(arch):
     assert logits.shape == (B, 1, cfg.vocab)
     assert np.all(np.isfinite(np.asarray(logits, np.float32)))
     assert jax.tree.structure(new_caches) == jax.tree.structure(caches)
+
+
+# --------------------------------------------------------------------------
+# Named scopes reach the optimised HLO (what trace readers join on)
+# --------------------------------------------------------------------------
+
+def _hlo_scopes(compiled) -> set[str]:
+    """The :data:`SCOPES` among the ``op_name`` path components of an
+    optimised HLO, ``jvp(``/``transpose(`` wrappers removed."""
+    from repro.models.lm import SCOPES
+    parts = set()
+    for op in re.findall(r'op_name="([^"]*)"', compiled.as_text()):
+        parts |= {re.sub(r"^(?:\w+\()+|\)+$", "", p) for p in op.split("/")}
+    return parts & set(SCOPES)
+
+
+@pytest.mark.parametrize("arch", list_archs())
+def test_decode_hlo_names_each_layer(arch):
+    """The gated decode step's optimised HLO carries a scope for each
+    mixer kind of the config (``attn`` for cross-attention and MLA too),
+    the FFN, the layer scan, the cache gate and the head."""
+    cfg = get_config(arch, smoke=True)
+    lm = LM(cfg, remat="none")
+    params, _ = lm.init(RNG)
+    B = 2
+    batch = {"pos": jnp.zeros((B,), jnp.int32),
+             "active": jnp.ones((B,), bool)}
+    if cfg.frontend == "audio_frames":
+        batch["frames"] = jnp.zeros((B, 1, cfg.d_model), jnp.bfloat16)
+    else:
+        batch["tokens"] = jnp.zeros((B, 1), jnp.int32)
+    if cfg.frontend == "vision":
+        batch["img_embeds"] = jnp.zeros((B, cfg.n_img_tokens, cfg.d_model),
+                                        jnp.bfloat16)
+    caches = lm.init_caches(B, 8, vector_pos=True)
+    found = _hlo_scopes(jax.jit(lm.decode_step).lower(
+        params, batch, caches).compile())
+    kinds = cfg.layer_kinds()
+    want = {"attn" if mix == "xattn" else mix for mix, _ in kinds}
+    want |= {"cache_gate", "head"}
+    if any(repeats > 1 for _, repeats in cfg.layer_groups()):
+        want.add("layer_scan")
+    if any(ffn != "none" for _, ffn in kinds):
+        want.add("ffn")
+    if cfg.frontend != "audio_frames":
+        want.add("embed")
+    assert want <= found, want - found
+
+
+def test_train_hlo_names_each_layer():
+    """The full-remat train step's optimised HLO carries the layer scopes
+    through the layer scan, the recompute and the backward pass, and the
+    loss's and the optimizer's."""
+    from repro.optim import AdamW
+    cfg = get_config("smollm-135m", smoke=True)
+    lm = LM(cfg, remat="full")
+    opt = AdamW()
+    params, _ = lm.init(RNG)
+
+    def step(p, s, b):
+        (loss, _), g = jax.value_and_grad(lm.loss_fn, has_aux=True)(p, b)
+        return opt.update(g, s, p), loss
+
+    text = jax.jit(step).lower(params, opt.init(params),
+                               _batch_for(cfg, 2, 16)).compile()
+    assert {"embed", "attn", "ffn", "layer_scan", "head", "loss",
+            "optimizer"} <= _hlo_scopes(text)
+    ops = re.findall(r'op_name="([^"]*)"', text.as_text())
+    assert any("transpose(" in op and "/attn/" in op for op in ops)
 
 
 # --------------------------------------------------------------------------

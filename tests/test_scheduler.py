@@ -99,7 +99,39 @@ def test_slot_reuse_and_occupancy(served):
     assert 0.0 < rep.occupancy <= 1.0
     assert rep.generated == sum(gen for _, gen, _ in trace)
     d = rep.to_dict()
-    assert d["tok_per_s"] > 0 and d["latency_p99_s"] >= d["latency_p50_s"]
+    assert d["tok_per_s"] > 0
+    for r in rep.requests:
+        assert r.t_admit <= r.t_first <= r.t_done
+
+
+SERVE_SPANS = {"serve.run", "serve.admit", "serve.prefill.wait",
+               "serve.sample", "serve.decode.dispatch", "serve.decode.wait"}
+
+
+def test_run_emits_every_serve_span(served, tmp_path):
+    """A profiled ``run()`` on the CPU names its host work with every
+    ``serve.*`` span, each admit group with its bucket and width, on the
+    clock of the profiler's other events."""
+    from jax.profiler import ProfileData
+    cfg, lm, params = served
+    _run(cfg, lm, params, _trace(cfg, n=3))            # compiles outside
+    jax.profiler.start_trace(str(tmp_path))
+    rep = _run(cfg, lm, params, _trace(cfg, n=3), slots=2)
+    jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    events = [ev for plane in ProfileData.from_file(str(path)).planes
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("serve.")]
+    assert {ev.name for ev in events} == SERVE_SPANS
+    (run,) = [ev for ev in events if ev.name == "serve.run"]
+    assert all(run.start_ns <= ev.start_ns and
+               ev.start_ns + ev.duration_ns <= run.start_ns + run.duration_ns
+               for ev in events)
+    admits = [dict(ev.stats) for ev in events if ev.name == "serve.admit"]
+    assert sum(a["width"] for a in admits) == len(rep.requests)
+    assert all(a["bucket"] == prefill_bucket(1) for a in admits)
+    steps = [ev for ev in events if ev.name == "serve.decode.dispatch"]
+    assert len(steps) == rep.steps
 
 
 def test_eos_evicts_early(served):
