@@ -11,8 +11,8 @@ One chip, in order, in this one process (it starts no child):
 1. kernels — the five Pallas kernels compiled, at the widths of the
    models that use them, against their ``ref.py`` oracles;
 2. serve — ``repro.launch.serve.main`` on the full config: every request
-   finishes, prefill logits are finite, at least two requests stream
-   exactly ``scheduler.decode_offline``'s tokens, and every other one
+   finishes, prefill logits and caches are finite, at least two requests
+   stream exactly ``scheduler.decode_offline``'s tokens, and every other one
    stays offline-greedy up to a last-bit near-tie (the bf16 logits of a
    random-weight model tie often, and batch-8 and batch-1 steps may
    round their last bit apart);
@@ -162,10 +162,12 @@ def serve_phase(argv: list[str], n_oracle: int) -> list[str]:
         toks = np.zeros((len(trace), width), np.int32)
         for i, t in enumerate(trace):
             toks[i, :t["prompt_len"]] = t["prompt"]
-        logits = jax.jit(srv.lm.prefill)(srv.params,
-                                         {"tokens": jnp.asarray(toks)})
-        if not bool(jnp.isfinite(logits).all()):
-            problems.append("serve: non-finite prefill logits")
+        lengths = jnp.asarray([t["prompt_len"] for t in trace], jnp.int32)
+        filled = jax.jit(srv.lm.prefill)(
+            srv.params, {"tokens": jnp.asarray(toks), "lengths": lengths})
+        if not all(bool(jnp.isfinite(x).all())
+                   for x in jax.tree.leaves(filled)):
+            problems.append("serve: non-finite prefill logits or caches")
 
         order = sorted(range(len(trace)), key=lambda i: (
             trace[i]["prompt_len"] + trace[i]["max_new"], i))

@@ -23,11 +23,14 @@ Correctness rests on three model-layer properties (``models/``):
   to offline per-request decode (:func:`decode_offline`;
   ``tests/test_scheduler.py`` pins this).
 
-Prefill runs per request at batch 1, padded to a power-of-two bucket
-(:func:`prefill_bucket`) so at most ``log2`` distinct lengths ever
-compile, as a ``lax.scan`` of gated ``decode_step``s — exact for every
-architecture including the recurrent mixers, which have no fused
-prefill.  The filled cache is scattered into the free slot.
+Prefill runs per group of same-bucket requests, padded to a
+power-of-two bucket (:func:`prefill_bucket`) so at most ``log2``
+distinct lengths ever compile.  Where every block is self-attention
+with a GQA cache (``LM.prefill_fills_caches``) it is one full-sequence
+forward per request, ``LM.prefill``, whose K/V fill the cache; other
+configs (recurrent mixers, MLA's latent cache, cross-attention) run a
+``lax.scan`` of gated ``decode_step``s.  The filled rows are scattered
+into the free slots.
 
 RNG: every request owns an independent stream,
 ``fold_in(PRNGKey(seed), request_id)``, and every draw inside it is
@@ -46,6 +49,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
+
+from ..models.attention import KVCache
 
 #: Per-model jit memo: ``jax.jit(lm.decode_step)`` binds a *new*
 #: function object every time, so naively jitting in each batcher (or
@@ -90,6 +95,18 @@ def _jitted_step(lm):
         fn = cache["step"] = _jit(lm.decode_step)
     return fn
 
+
+def _fit(filled, caches):
+    """``LM.prefill``'s K/V cut to the length of ``caches``: the rows past
+    it are padding, since :meth:`ContinuousBatcher.submit` keeps every
+    request inside the cache."""
+    def cut(f, c):
+        n = c.k.shape[-3]
+        return KVCache(f.k[..., :n, :, :], f.v[..., :n, :, :], f.pos)
+
+    return jax.tree.map(cut, filled, caches,
+                        is_leaf=lambda x: isinstance(x, KVCache))
+
 __all__ = ["Request", "ServeReport", "ContinuousBatcher", "decode_offline",
            "greedy_margins", "run_static", "prefill_bucket"]
 
@@ -98,7 +115,12 @@ __all__ = ["Request", "ServeReport", "ContinuousBatcher", "decode_offline",
 _IMG_TAG = 0x494D47
 
 
-def prefill_bucket(length: int, minimum: int = 16) -> int:
+#: The smallest prefill bucket: the batcher and :func:`decode_offline`
+#: pad a prompt to the same bucket.
+PREFILL_MIN = 16
+
+
+def prefill_bucket(length: int, minimum: int = PREFILL_MIN) -> int:
     """Smallest power-of-two ≥ ``length`` (floor ``minimum``) — the
     padded prefill length, bounding distinct compiles to log2."""
     b = max(minimum, 1)
@@ -136,6 +158,8 @@ class ServeReport:
     wall_s: float = 0.0
     occupancy: float = 0.0      # mean active-slot fraction per decode step
     slots: int = 0
+    #: admit groups prefilled by each path, ``"pass"`` or ``"scan"``.
+    prefill_groups: dict[str, int] = field(default_factory=dict)
 
     @property
     def tok_per_s(self) -> float:
@@ -152,7 +176,8 @@ class ServeReport:
                 "decode_tok_per_s": self.decode_tok_per_s,
                 "prefill_s": self.prefill_s, "decode_s": self.decode_s,
                 "wall_s": self.wall_s, "occupancy": self.occupancy,
-                "slots": self.slots}
+                "slots": self.slots,
+                "prefill_groups": dict(self.prefill_groups)}
 
 
 def _request_key(seed: int, rid: int) -> jax.Array:
@@ -196,12 +221,10 @@ class ContinuousBatcher:
         seed: root of every RNG stream (see module docstring).
         eos_id: token id that finishes a request early (``None``
             disables EOS detection — length-only termination).
-        prefill_min: minimum prefill bucket (power-of-two padding).
     """
 
     def __init__(self, lm, params, *, slots: int, s_max: int,
-                 seed: int = 0, eos_id: int | None = None,
-                 prefill_min: int = 16):
+                 seed: int = 0, eos_id: int | None = None):
         cfg = lm.cfg
         if any(ffn == "moe" for _, ffn in cfg.layer_kinds()):
             raise ValueError(
@@ -213,7 +236,10 @@ class ContinuousBatcher:
         self.cfg = cfg
         self.slots, self.s_max, self.seed = slots, s_max, seed
         self.eos_id = eos_id
-        self.prefill_min = prefill_min
+        #: ``"pass"``: one ``LM.prefill`` forward fills a request's
+        #: caches (every block self-attention GQA); ``"scan"``: a scan of
+        #: gated decode steps (recurrent mixers, MLA, cross-attention).
+        self.prefill_path = "pass" if lm.prefill_fills_caches else "scan"
 
         self.caches = lm.init_caches(slots, s_max, vector_pos=True)
         self._step = _jitted_step(lm)
@@ -251,19 +277,70 @@ class ContinuousBatcher:
     # -- prefill side step -----------------------------------------------
     def _prefill_fn(self, bucket: int, k: int):
         """One jitted executable per (bucket, group-width) doing the
-        whole admit-side device work in a *single* dispatch: scan the
-        gated prompt steps for ``k`` same-bucket requests at once over
-        a zero batch-``k`` cache, scatter each filled row into its
-        target slot of the batch cache, and gather each request's
-        last-prompt-step logits.  Batch-1 python prefill + per-leaf
+        whole admit-side device work in a *single* dispatch: prefill
+        ``k`` same-bucket requests at once, scatter each filled row into
+        its target slot of the batch cache, and gather each request's
+        last-prompt-position logits.  Batch-1 python prefill + per-leaf
         install was ~15 ms of dispatch per admit — more than the decode
         steps it was feeding — and burst admits (server start, a wave
-        finishing together) prefill ``k`` requests for the price of
-        one scan."""
+        finishing together) prefill ``k`` requests for the price of one.
+
+        The program takes ``(params, xs, lengths, big, slot_vec, small,
+        img)`` on either path, ``xs`` time-major ``(bucket, k, 1[, d])``.
+        On the ``"pass"`` path it runs one ``LM.prefill`` forward per
+        request, whose K/V land at ``[slot, 0:bucket]``, cut to the cache
+        (``small`` and ``img`` are unused: no config that takes this path
+        has an image frontend); on the ``"scan"`` path it scans the gated
+        prompt steps over ``small``, a zero batch-``k`` cache
+        (:meth:`_zero_cache`), and installs whole rows."""
         cache = _jit_cache(self.lm)
         fn = cache.get(("prefill", bucket, k))
         if fn is not None:
             return fn
+        prefill = (self._pass_prefill if self.prefill_path == "pass"
+                   else self._scan_prefill)(bucket, k)
+        fn = cache[("prefill", bucket, k)] = _jit(prefill)
+        return fn
+
+    def _pass_prefill(self, bucket: int, k: int):
+        cfg, lm = self.cfg, self.lm
+        groups = lm._groups()
+
+        def row(params, big, r):
+            """One request's prefill at width 1 and its install."""
+            batch = {key: r[key][None] for key in r if key != "slot"}
+            logits, filled = lm.prefill(params, batch)
+            filled = _fit(filled, big)
+            out = {}
+            with jax.named_scope("prefill_install"):
+                for gi, (_pattern, repeats) in enumerate(groups):
+                    ax = 1 if repeats > 1 else 0
+                    g = f"group{gi}"
+
+                    def ins(b, f, ax=ax):
+                        start = [0] * b.ndim
+                        start[ax] = r["slot"]
+                        return jax.lax.dynamic_update_slice(
+                            b, f.astype(b.dtype), start)
+
+                    out[g] = jax.tree.map(ins, big[g], filled[g])
+            return out, logits[0, 0]
+
+        def prefill(params, xs, lengths, big, slot_vec, small, img):
+            # Rows run one after another, each at width 1: a row's
+            # arithmetic is then its width-1 program's whatever the group
+            # width (the CPU's matmuls round a row differently at other
+            # widths), so it streams what ``decode_offline`` does.
+            rows = {"lengths": lengths, "slot": slot_vec}
+            if cfg.frontend == "audio_frames":
+                rows["frames"] = jnp.swapaxes(xs[:, :, 0], 0, 1)
+            else:
+                rows["tokens"] = xs[:, :, 0].T
+            return jax.lax.scan(functools.partial(row, params), big, rows)
+
+        return prefill
+
+    def _scan_prefill(self, bucket: int, k: int):
         cfg, lm = self.cfg, self.lm
         groups = lm._groups()
 
@@ -303,8 +380,7 @@ class ContinuousBatcher:
                 logits, (lengths - 1)[None, :, None], axis=0)[0]
             return out, last                               # (k, vocab)
 
-        fn = cache[("prefill", bucket, k)] = _jit(prefill)
-        return fn
+        return prefill
 
     def _zero_cache(self, k: int):
         """Immutable zero batch-``k`` cache template, built once per
@@ -349,10 +425,11 @@ class ContinuousBatcher:
         img = (jnp.concatenate(
             [_image_of(kk, cfg.n_img_tokens, cfg.d_model) for kk in keys])
             if cfg.frontend == "vision" else None)
-        fn = self._prefill_fn(bucket, k)
-        self.caches, last = fn(
+        small = (self._zero_cache(k) if self.prefill_path == "scan"
+                 else None)
+        self.caches, last = self._prefill_fn(bucket, k)(
             self.params, xs, jnp.asarray(lengths), self.caches,
-            jnp.asarray(slot_vec), self._zero_cache(k), img)
+            jnp.asarray(slot_vec), small, img)
         with TraceAnnotation("serve.prefill.wait"):
             last_np = np.asarray(last)
         t_first = time.perf_counter()
@@ -431,13 +508,15 @@ class ContinuousBatcher:
                         break
                     if not self.active[slot]:
                         req = self.queue.popleft()
-                        b = prefill_bucket(req.prompt_len,
-                                           self.prefill_min)
+                        b = prefill_bucket(req.prompt_len)
                         groups.setdefault(b, []).append((slot, req))
                         rep.requests.append(req)
                 for b, pairs in sorted(groups.items()):
+                    path = self.prefill_path
+                    rep.prefill_groups[path] = (
+                        rep.prefill_groups.get(path, 0) + 1)
                     with TraceAnnotation("serve.admit", bucket=b,
-                                         width=len(pairs)):
+                                         width=len(pairs), path=path):
                         self._admit_group(pairs, b)
                 if groups:
                     rep.prefill_s += time.perf_counter() - t0
@@ -478,15 +557,54 @@ class ContinuousBatcher:
 
 # -- references ----------------------------------------------------------
 
+def _prefill_offline(lm, params, req: Request, key: jax.Array, caches):
+    """Where the batcher prefills in one pass: the same ``LM.prefill`` at
+    batch 1 over the prompt padded to its bucket, its K/V written into
+    ``caches`` (scalar positions).  Returns ``(logits (vocab,), caches)``,
+    or ``None`` on the scan path."""
+    cfg = lm.cfg
+    if not lm.prefill_fills_caches:
+        return None
+    L = req.prompt_len
+    bucket = prefill_bucket(L)
+    batch = {"lengths": jnp.asarray([L], jnp.int32)}
+    if cfg.frontend == "audio_frames":
+        batch["frames"] = jnp.concatenate(
+            [_frames_at(key, t, cfg.d_model) for t in range(L)]
+            + [jnp.zeros((1, bucket - L, cfg.d_model), jnp.bfloat16)],
+            axis=1)
+    else:
+        toks = np.zeros((1, bucket), np.int32)
+        toks[0, :L] = req.prompt
+        batch["tokens"] = jnp.asarray(toks)
+    memo = _jit_cache(lm)
+    fn = memo.get("one_pass")
+    if fn is None:
+        fn = memo["one_pass"] = _jit(lm.prefill)
+    logits, filled = fn(params, batch)
+
+    def fill(c, f):
+        n = f.k.shape[-3]
+        return KVCache(c.k.at[..., :n, :, :].set(f.k),
+                       c.v.at[..., :n, :, :].set(f.v),
+                       jnp.full(c.pos.shape, L, c.pos.dtype))
+
+    caches = jax.tree.map(fill, caches, _fit(filled, caches),
+                          is_leaf=lambda x: isinstance(x, KVCache))
+    return logits[0, -1], caches
+
+
 def decode_offline(lm, params, req: Request, *, seed: int, s_max: int,
                    eos_id: int | None = None) -> list[int]:
     """Single-request lock-step decode — the scheduler's oracle.
 
     Deliberately a *different* code path from the batcher: scalar cache
     positions (``dynamic_update_slice`` writes instead of per-slot
-    scatter), no padding, no gating, batch 1 throughout.  Row
-    independence says the streamed tokens must match exactly;
-    ``tests/test_scheduler.py`` asserts it."""
+    scatter), no gating, batch 1 throughout, and the prompt prefilled
+    alone: by one ``LM.prefill`` over the prompt padded to its bucket
+    where the batcher prefills in one pass, else one decode
+    step per prompt token, unpadded.  Row independence says the streamed
+    tokens must match exactly; ``tests/test_scheduler.py`` asserts it."""
     cfg = lm.cfg
     key = _request_key(seed, req.rid)
     caches = lm.init_caches(1, s_max)
@@ -507,11 +625,14 @@ def decode_offline(lm, params, req: Request, *, seed: int, s_max: int,
             batch["img_embeds"] = img
         return batch
 
-    logits = None
-    for t in range(req.prompt_len):
-        logits, caches = step(params, batch_at(t, None), caches)
+    first = _prefill_offline(lm, params, req, key, caches)
+    if first is None:
+        for t in range(req.prompt_len):
+            logits, caches = step(params, batch_at(t, None), caches)
+        first = logits[0, -1], caches
+    last, caches = first
     out: list[int] = []
-    tok = _sample(np.asarray(logits[0, -1]), key, req.prompt_len - 1,
+    tok = _sample(np.asarray(last), key, req.prompt_len - 1,
                   req.temperature)
     out.append(tok)
     t = req.prompt_len
@@ -536,19 +657,29 @@ def greedy_margins(lm, params, req: Request, tokens: list[int], *,
     left the model (hundreds).  Token-stream frontends only."""
     caches = lm.init_caches(1, s_max)
     step = _jitted_step(lm)
-    feed = list(np.asarray(req.prompt).reshape(-1)) + list(tokens[:-1])
     margins = np.zeros(len(tokens), np.float32)
-    for t, tok in enumerate(feed):
+
+    def margin(j, row):
+        eps = float(jnp.finfo(row.dtype).eps)
+        row = np.asarray(row, np.float32)
+        top = float(row.max())
+        ulp = eps * 2.0 ** np.floor(np.log2(max(abs(top), 1e-30)))
+        margins[j] = (top - row[tokens[j]]) / ulp
+
+    feed = list(np.asarray(req.prompt).reshape(-1)) + list(tokens[:-1])
+    first = _prefill_offline(lm, params, req, None, caches)
+    t0 = 0
+    if first is not None:
+        last, caches = first
+        margin(0, last)
+        t0 = req.prompt_len
+    for t in range(t0, len(feed)):
         batch = {"pos": jnp.asarray(t, jnp.int32),
-                 "tokens": jnp.asarray(tok, jnp.int32).reshape(1, 1)}
+                 "tokens": jnp.asarray(feed[t], jnp.int32).reshape(1, 1)}
         logits, caches = step(params, batch, caches)
         j = t - (req.prompt_len - 1)
         if j >= 0:
-            eps = float(jnp.finfo(logits.dtype).eps)
-            row = np.asarray(logits[0, -1], np.float32)
-            top = float(row.max())
-            ulp = eps * 2.0 ** np.floor(np.log2(max(abs(top), 1e-30)))
-            margins[j] = (top - row[tokens[j]]) / ulp
+            margin(j, logits[0, -1])
     return margins
 
 

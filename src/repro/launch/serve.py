@@ -149,7 +149,7 @@ def build(args: argparse.Namespace) -> Server:
     mesh, mspec = host_mesh_and_spec()
     pl_lo, pl_hi = args.prompt_len_range
     g_lo, g_hi = args.gen_range
-    s_max = prefill_bucket(pl_hi, 16) + g_hi
+    s_max = prefill_bucket(pl_hi) + g_hi
 
     plan, plan_info = (None, {"source": "skipped", "fetch_ms": 0.0}) \
         if args.no_plan else fetch_plan(

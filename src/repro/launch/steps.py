@@ -193,6 +193,8 @@ def build_serve_step(cfg: ArchConfig, shape: ShapeSpec, mesh: Mesh,
 
 def build_prefill_step(cfg: ArchConfig, shape: ShapeSpec, mesh: Mesh,
                        plan: ShardingPlan, use_kernels: bool = False):
+    """The served prefill, ``LM.prefill`` over full-length rows: the last
+    logits, and for self-attention GQA configs the caches it fills."""
     lm = LM(cfg, plan=plan, mesh=mesh, remat="none",
             use_kernels=use_kernels)
     params_abs, dims = lm.init(None, abstract=True)

@@ -179,9 +179,13 @@ def gqa_attention(x: jax.Array, p: dict, cfg: ArchConfig,
                   kv_x: jax.Array | None = None,
                   causal: bool = True,
                   use_kernels: bool = False,
+                  return_kv: bool = False,
                   ) -> tuple[jax.Array, KVCache | None]:
     """Self- or cross-attention.  ``cache`` implies single-step decode;
-    ``kv_x`` switches to cross-attention over a context stream."""
+    ``kv_x`` switches to cross-attention over a context stream.
+    ``return_kv`` (no ``cache``) also returns the post-rotary ``k``/``v``
+    it attended over, ``KVCache(k, v, None)``: the one-pass prefill's
+    cache rows."""
     Dh = cfg.resolved_head_dim
     rot_dim = int(Dh * cfg.rope_pct) & ~1
 
@@ -231,6 +235,8 @@ def gqa_attention(x: jax.Array, p: dict, cfg: ArchConfig,
             mask = (causal_mask(x.shape[1], k.shape[1], cfg.attn_window)
                     if is_causal else None)
             ctx = _sdpa(q, k, v, mask)
+        if return_kv:
+            new_cache = KVCache(k, v, None)
 
     ctx = constrain(ctx, ("batch", "seq", "heads", "d_head"), "attn_ctx")
     out = jnp.einsum("bshk,hkd->bsd", ctx, p["w_o"])

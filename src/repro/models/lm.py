@@ -10,8 +10,9 @@ axis.
 Entry points:
 
 * ``loss_fn(params, batch)``    — training loss (+ MoE aux, MTP).
-* ``prefill(params, batch)``    — full-sequence forward; returns logits
-  and initialised caches.
+* ``prefill(params, batch)``    — full-sequence forward; returns each
+  row's last logits and, for self-attention GQA configs, the filled
+  caches.
 * ``decode_step(params, batch, caches)`` — one-token step with KV / SSM /
   xLSTM state caches.
 * ``init_caches(B, S_max)``     — abstract-friendly cache pytree.
@@ -142,7 +143,8 @@ class LM:
         return pb.params, pb.dims
 
     # -- one block ----------------------------------------------------------------
-    def _block(self, resid, bp, mix, ffn, positions, img, cache=None):
+    def _block(self, resid, bp, mix, ffn, positions, img, cache=None,
+               emit_kv=False):
         cfg = self.cfg
         c = self.constrain
         aux = MoEAux(jnp.zeros(()), jnp.zeros(()), jnp.zeros(()))
@@ -158,8 +160,9 @@ class LM:
                     out, kvc = gqa_attention(
                         x, bp["mix"], cfg, positions, c, cache=cache,
                         kv_x=kv_x,
-                        use_kernels=self.use_kernels and cache is None)
-                new_cache = kvc if cache is not None else None
+                        use_kernels=self.use_kernels and cache is None,
+                        return_kv=emit_kv)
+                new_cache = kvc if cache is not None or emit_kv else None
             elif mix == "mamba":
                 if cache is not None:
                     state, carry = cache
@@ -198,15 +201,15 @@ class LM:
         return resid, aux, new_cache
 
     def _super_block(self, resid, gparams, pattern, positions, img,
-                     caches=None):
+                     caches=None, emit_kv=False):
         auxes = []
-        new_caches = {} if caches is not None else None
+        new_caches = {} if caches is not None or emit_kv else None
         for j, (mix, ffn) in enumerate(pattern):
             cache = caches.get(f"b{j}") if caches is not None else None
             resid, aux, nc = self._block(resid, gparams[f"b{j}"], mix, ffn,
-                                         positions, img, cache)
+                                         positions, img, cache, emit_kv)
             auxes.append(aux)
-            if caches is not None:
+            if new_caches is not None:
                 new_caches[f"b{j}"] = nc
         total_aux = MoEAux(
             sum(a.load_balance_loss for a in auxes),
@@ -215,20 +218,25 @@ class LM:
         return resid, total_aux, new_caches
 
     # -- forward -------------------------------------------------------------------
-    def _backbone(self, params, resid, positions, img, caches=None):
-        """Runs all layer groups; returns (resid, aux, new_caches)."""
+    def _backbone(self, params, resid, positions, img, caches=None,
+                  emit_kv=False):
+        """Runs all layer groups; returns (resid, aux, new_caches).
+        ``emit_kv`` (no ``caches``): new_caches holds each attention
+        layer's full-sequence ``KVCache(k, v, None)``, stacked over a
+        scanned group's layers."""
         cfg = self.cfg
         lb = jnp.zeros(())
         zl = jnp.zeros(())
-        new_caches = {} if caches is not None else None
+        new_caches = {} if caches is not None or emit_kv else None
         for gi, (pattern, repeats) in enumerate(self._groups()):
             gparams = params[f"group{gi}"]
             gcaches = caches.get(f"group{gi}") if caches is not None else None
             if repeats == 1:
                 resid, aux, nc = self._super_block(
-                    resid, gparams, pattern, positions, img, gcaches)
+                    resid, gparams, pattern, positions, img, gcaches,
+                    emit_kv)
                 lb, zl = lb + aux.load_balance_loss, zl + aux.router_z_loss
-                if caches is not None:
+                if new_caches is not None:
                     new_caches[f"group{gi}"] = nc
                 continue
 
@@ -239,7 +247,7 @@ class LM:
                 else:
                     lp, lc = xs, None
                 r, aux, nc = self._super_block(r, lp, pattern, positions,
-                                               img, lc)
+                                               img, lc, emit_kv)
                 return ((r, lb_c + aux.load_balance_loss,
                          zl_c + aux.router_z_loss), nc)
 
@@ -253,7 +261,7 @@ class LM:
             with jax.named_scope("layer_scan"):
                 (resid, lb, zl), scanned_caches = jax.lax.scan(
                     body, (resid, lb, zl), xs)
-            if caches is not None:
+            if new_caches is not None:
                 new_caches[f"group{gi}"] = scanned_caches
         return resid, (lb, zl), new_caches
 
@@ -435,19 +443,52 @@ class LM:
                               z((B, D), F32), z((B, D), F32))
         return None
 
-    def prefill(self, params, batch) -> tuple[jax.Array, dict]:
-        """Full-sequence forward returning last-position logits and caches
-        filled for subsequent decode."""
+    @property
+    def prefill_fills_caches(self) -> bool:
+        """Whether :meth:`prefill` fills the caches: every block is
+        self-attention with a GQA cache.  A recurrent mixer's state, MLA's
+        latent cache and cross-attention are filled by decode steps."""
+        return self.cfg.mla is None and all(
+            mix == "attn" for mix, _ in self.cfg.layer_kinds())
+
+    def prefill(self, params, batch) -> tuple[jax.Array, dict | None]:
+        """Full-sequence forward over ``(B, S)`` prompts at positions
+        ``0..S-1``.  ``batch["lengths"]`` (optional ``(B,)`` int32) holds
+        each row's prompt length; without it every row is ``S`` long.
+
+        Returns ``(logits, caches)``: each row's logits at its last prompt
+        position, ``(B, 1, vocab)``, and where :attr:`prefill_fills_caches`
+        the caches, laid out as ``init_caches(B, S, vector_pos=True)``,
+        with every layer's post-rotary K/V at positions ``0..S-1`` and
+        ``pos = lengths`` (``None`` for other configs).  Rows past a
+        prompt's length hold the K/V of its padding, which causal
+        attention keeps from every real position and decode overwrites
+        before it reads."""
         cfg = self.cfg
         if cfg.frontend == "audio_frames":
             B, S = batch["frames"].shape[:2]
         else:
             B, S = batch["tokens"].shape
+        lengths = batch.get("lengths")
+        if lengths is None:
+            lengths = jnp.full((B,), S, jnp.int32)
+        fill = self.prefill_fills_caches
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
         resid, img = self._embed(params, batch)
-        resid, _, _ = self._backbone(params, resid, positions, img)
-        logits = self._head(params, resid[:, -1:])
-        return logits
+        resid, _, kv = self._backbone(params, resid, positions, img,
+                                      emit_kv=fill)
+        last = jnp.take_along_axis(resid, (lengths - 1)[:, None, None],
+                                   axis=1)
+        logits = self._head(params, last)
+        if not fill:
+            return logits, None
+        caches = {}
+        for gi, (_pattern, repeats) in enumerate(self._groups()):
+            pos = (jnp.broadcast_to(lengths, (repeats, B)) if repeats > 1
+                   else lengths)
+            caches[f"group{gi}"] = {
+                b: KVCache(c.k, c.v, pos) for b, c in kv[f"group{gi}"].items()}
+        return logits, caches
 
     def decode_step(self, params, batch, caches) -> tuple[jax.Array, dict]:
         """One-token step: batch holds the current token (B,1) (or frame)
