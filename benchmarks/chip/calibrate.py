@@ -72,10 +72,11 @@ def train_readings(ctx, drv, seeds) -> list[dict]:
         job = drv.Job(ctx, built)
         fed, losses, g, d = job.first_steps()
         job.close()
-        ref = drv.reference_steps(ctx.spec, t, seed, fed)
-        ctrl = drv.reference_steps(ctx.spec, t, seed, fed, quant=CONTROL)
+        ref = drv.reference_steps(ctx.spec, t, seed, fed, built)
+        ctrl = drv.reference_steps(ctx.spec, t, seed, fed, built,
+                                   quant=CONTROL)
         half = [{k: v[:len(v) // 2] for k, v in h.items()} for h in fed]
-        fault = drv.reference_steps(ctx.spec, t, seed, half)
+        fault = drv.reference_steps(ctx.spec, t, seed, half, built)
         row = {"seed": seed,
                "program": drv.compare(losses, g, d, *ref),
                "control": drv.compare(*ctrl, *ref),

@@ -4,7 +4,10 @@ call; what an implementation recomputes or re-reads does not count.
 """
 from __future__ import annotations
 
-from model import Spec
+from typing import TYPE_CHECKING
+
+if TYPE_CHECKING:
+    from families.dense import Spec
 
 
 def layer_matmul_params(s: Spec) -> int:

@@ -97,7 +97,7 @@ def run(ctx: harness.Context) -> harness.Outcome:
     srv.start()
     w = window(ctx, srv, ctx.seed, ctx.seconds, prof)
     srv.stop()
-    mem = harness.memory_peak(1)
+    mem = harness.memory_peak(ctx.chips)
 
     e2e = {"serve_tok_s": w.tokens / ctx.seconds}
     ctx.note(f"window: {w.tokens} tokens, serve_tok_s "

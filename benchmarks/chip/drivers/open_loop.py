@@ -104,7 +104,7 @@ def run(ctx: harness.Context) -> harness.Outcome:
     srv.start()
     w = window(ctx, srv, plan, t["preroll_s"], ctx.seconds, prof)
     srv.stop()
-    mem = harness.memory_peak(1)
+    mem = harness.memory_peak(ctx.chips)
 
     ttft, tpot, due_in = latencies(w)
     late = [s.sent - s.due for s in w.sent]

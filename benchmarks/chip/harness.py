@@ -3,10 +3,13 @@ device check, the compile cache, compile counting, the profiler window,
 the metric readers and the result line.
 
 A cell is an entry of ``BENCHMARK.json``'s ``workloads``.  Its config
-is ``configs/<config>.json``, its traffic ``traffic/<traffic>.json``,
-whose ``kind`` names the driver ``drivers/<kind>.py``; each per-layer
-metric is read by ``metrics/<name>.py``.  Nothing here lists cells,
-configs, mixes or metrics.
+is ``configs/<config>.json``, whose ``family`` names the yardstick
+``families/<family>.py`` and the costs ``costs/<family>.py``; its
+traffic is ``traffic/<traffic>.json``, whose ``kind`` names the driver
+``drivers/<kind>.py`` and whose ``mesh`` and ``fsdp`` say how the
+cell's chips are laid out; each per-layer metric is read by
+``metrics/<name>.py``.  Nothing here lists cells, configs, families,
+mixes or metrics.
 """
 from __future__ import annotations
 
@@ -19,6 +22,9 @@ import threading
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
+
+import model
+from model import ConfigMismatch
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
@@ -57,8 +63,9 @@ def limits(cell: str, smoke: bool = False) -> dict:
 
 
 def load_module(kind: str, name: str):
-    """``drivers/<name>.py``, ``metrics/<name>.py`` or ``costs/<name>.py``
-    (names may hold dots, so they are loaded by path)."""
+    """``drivers/<name>.py``, ``metrics/<name>.py``, ``costs/<name>.py``
+    or ``families/<name>.py`` (names may hold dots, so they are loaded by
+    path)."""
     path = HERE / kind / f"{name}.py"
     if not path.exists():
         raise KeyError(f"no {kind} module {name!r} ({path})")
@@ -68,8 +75,17 @@ def load_module(kind: str, name: str):
         return sys.modules[spec.name]
     mod = importlib.util.module_from_spec(spec)
     sys.modules[spec.name] = mod
-    spec.loader.exec_module(mod)
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        del sys.modules[spec.name]
+        raise
     return mod
+
+
+def family(name: str):
+    """The yardstick of a model family: ``families/<name>.py``."""
+    return load_module("families", name)
 
 
 def cell_metrics(bench: dict, cell: str, section: str) -> list[dict]:
@@ -195,8 +211,8 @@ class Context:
     run's arguments."""
     cell: str
     conf: dict                    # configs/<config>.json
-    spec: object                  # model.Spec of conf
-    arch_cfg: object              # the program's ArchConfig
+    spec: object                  # the family's Spec of conf
+    arch_cfg: object              # the program's ArchConfig, cut to conf
     traffic: dict
     seed: int
     seconds: float
@@ -207,6 +223,10 @@ class Context:
     smoke: bool = False           # the program's smoke-size config (tests)
     device: dict = field(default_factory=dict)   # as JAX reports it
     compiles: CompileCounter | None = None
+    chips: int = 1
+    mesh: object = None           # the (data, model) mesh of the chips
+    mesh_spec: object = None      # the MeshSpec the plan is derived for
+    fsdp: bool = False            # the plan also shards weights over data
 
     @staticmethod
     def note(msg: str) -> None:
@@ -261,41 +281,56 @@ def result_line(bench: dict, cell: str, out: Outcome, device: dict,
     return line
 
 
-class ConfigMismatch(RuntimeError):
-    """The program's config departs from the configuration file."""
+def mesh_shape(traffic: dict, chips: int) -> tuple[int, int]:
+    """The mix's ``mesh`` (``{"data": d, "model": m}``, ``d * m`` the
+    cell's chips), by default ``(data=chips, model=1)``."""
+    shape = traffic.get("mesh", {"data": chips, "model": 1})
+    if shape["data"] * shape["model"] != chips:
+        raise ConfigMismatch(f"mesh {shape} is not the cell's {chips} chips")
+    return shape["data"], shape["model"]
+
+
+def cell_mesh(shape: tuple[int, int]):
+    """The ``(data, model)`` mesh over the first ``data * model`` devices
+    and the ``MeshSpec`` the plan is derived for."""
+    from repro.core import MeshSpec
+    from repro.launch.mesh import make_host_mesh
+    d, m = shape
+    return make_host_mesh((d, m)), MeshSpec((("data", d), ("model", m)))
 
 
 def cli_context(cell_name: str, seed: int, seconds: float, trace: bool,
                 t_process: float) -> Context:
-    """Everything before the driver: the device check (raises
-    :class:`NoDevice`), the compile cache, the config and its check
-    against the program's, the traffic and the limits."""
+    """Everything before the driver: the config, its cut and its check
+    against the program's, and the mix's mesh (each raises
+    :class:`ConfigMismatch` before any work), the device check (raises
+    :class:`NoDevice`), the compile cache, the mesh, the traffic and the
+    limits."""
     bench = benchmark()
     cell = workload(bench, cell_name)
     prepare_env()
+    from repro.configs import get_config
+    conf = model.load_config(cell["config"])
+    spec, arch_cfg = model.cut(conf, family(conf["family"]),
+                               get_config(conf["arch"]))
+    mix = traffic(cell["traffic"])
+    shape = mesh_shape(mix, cell["chips"])
     device = device_info(cell["chips"])
     import jax
 
-    from repro.configs import get_config
     from repro.launch.compile_cache import enable_compile_cache
-
-    import model
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    conf = model.load_config(cell["config"])
-    spec = model.spec_of(conf)
-    arch_cfg = get_config(conf["arch"])
-    bad = model.program_mismatches(spec, arch_cfg)
-    if bad:
-        raise ConfigMismatch(f"program config departs from "
-                             f"{cell['config']}: {bad}")
+    mesh, mesh_spec = cell_mesh(shape)
     out_dir = OUT_DIR / cell_name
     out_dir.mkdir(parents=True, exist_ok=True)
     return Context(cell=cell_name, conf=conf, spec=spec, arch_cfg=arch_cfg,
-                   traffic=traffic(cell["traffic"]), seed=seed,
-                   seconds=seconds, trace=trace, t_process=t_process,
-                   out_dir=out_dir, limits=limits(cell_name), device=device,
-                   compiles=CompileCounter())
+                   traffic=mix, seed=seed, seconds=seconds, trace=trace,
+                   t_process=t_process, out_dir=out_dir,
+                   limits=limits(cell_name), device=device,
+                   compiles=CompileCounter(), chips=cell["chips"],
+                   mesh=mesh, mesh_spec=mesh_spec,
+                   fsdp=bool(mix.get("fsdp", False)))
 
 
 def trace_span(traffic: dict, t_open: float, seconds: float

@@ -1,72 +1,77 @@
-"""A configuration file's published sizes, as the yardstick reads them.
+"""A configuration file, as the yardstick reads it, and the program's
+``ArchConfig`` cut to it.
+
+A configuration file ``configs/<name>.json`` holds, at its top level,
+the keys of the source's ``config.json`` with the values it is run at,
+and beside them:
+
+* ``name``, ``arch`` (the program's config), ``family`` (the yardstick
+  ``families/<family>.py``: sizes, weight layout, plain reference) and
+  ``source``;
+* ``reduced``: every key whose value here differs from the source, as
+  ``{"key", "published", "here", "why"}``; ``here`` is the value at the
+  top level;
+* ``deployment``: over how many chips each layer is divided, and how;
+* ``assumed``: what the source does not state and was set here.
 
 Nothing here imports the program: the reference, the weights, the costs
 and the check of the program's own config all start from this.
 """
 from __future__ import annotations
 
+import dataclasses
 import json
-from dataclasses import dataclass
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 
 
-@dataclass(frozen=True)
-class Spec:
-    name: str
-    arch: str
-    family: str
-    layers: int
-    d_model: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    norm: str            # "rms" | "layernorm"
-    eps: float
-    rope_theta: float
-    rot_dim: int         # rotary features per head (even)
-    tied: bool
-
-    @property
-    def kv_bytes_per_token(self) -> int:
-        """K and V of one position over all layers, in bf16."""
-        return 2 * self.layers * self.kv_heads * self.head_dim * 2
+class ConfigMismatch(RuntimeError):
+    """The program's config departs from the configuration file, or the
+    file's cut cannot be applied."""
 
 
 def load_config(name: str) -> dict:
     return json.loads((HERE / "configs" / f"{name}.json").read_text())
 
 
-def spec_of(conf: dict) -> Spec:
-    p = conf["published"]
-    head_dim = p.get("head_dim") or p["hidden_size"] // p["num_attention_heads"]
-    norm = p["norm"]
-    eps = p["rms_norm_eps"] if norm == "rms" else p["norm_eps"]
-    rot = int(head_dim * p.get("partial_rotary_factor", 1.0)) & ~1
-    return Spec(name=conf["name"], arch=conf["arch"], family=conf["family"],
-                layers=p["num_hidden_layers"], d_model=p["hidden_size"],
-                heads=p["num_attention_heads"],
-                kv_heads=p["num_key_value_heads"], head_dim=head_dim,
-                d_ff=p["intermediate_size"], vocab=p["vocab_size"],
-                norm=norm, eps=eps, rope_theta=p["rope_theta"],
-                rot_dim=rot, tied=bool(p["tie_word_embeddings"]))
+def field_value(obj, field: str):
+    """The value of a dotted field (``moe.n_experts``)."""
+    for name in field.split("."):
+        obj = getattr(obj, name)
+    return obj
 
 
-def program_mismatches(spec: Spec, arch_cfg) -> list[str]:
-    """Where the program's ``ArchConfig`` departs from the published
-    sizes.  A run on such a config is no run of the configuration."""
-    want = {"n_layers": spec.layers, "d_model": spec.d_model,
-            "n_heads": spec.heads, "n_kv_heads": spec.kv_heads,
-            "resolved_head_dim": spec.head_dim, "d_ff": spec.d_ff,
-            "vocab": spec.vocab, "tie_embeddings": spec.tied,
-            "norm": "rms" if spec.norm == "rms" else "ln"}
-    out = [f"{k}: program {getattr(arch_cfg, k)!r}, published {v!r}"
-           for k, v in want.items() if getattr(arch_cfg, k) != v]
-    rot = int(arch_cfg.resolved_head_dim * arch_cfg.rope_pct) & ~1
-    if rot != spec.rot_dim:
-        out.append(f"rotary features: program {rot}, published "
-                   f"{spec.rot_dim}")
-    return out
+def replace_field(obj, field: str, value):
+    """``dataclasses.replace`` on a dotted field (``moe.n_experts``)."""
+    head, _, rest = field.partition(".")
+    if not dataclasses.is_dataclass(obj) or head not in {
+            f.name for f in dataclasses.fields(obj)}:
+        raise ConfigMismatch(f"the program's config has no field {field!r}")
+    if rest:
+        value = replace_field(getattr(obj, head), rest, value)
+    return dataclasses.replace(obj, **{head: value})
+
+
+def cut(conf: dict, family, arch_cfg):
+    """The configuration as run, and the program's config cut to it: each
+    ``reduced`` entry is applied through the family's key -> field map.
+    Raises :class:`ConfigMismatch` where an entry disagrees with the
+    file, names a key no field holds, or the cut program still departs
+    from the sizes run."""
+    for r in conf["reduced"]:
+        key = r["key"]
+        if conf.get(key) != r["here"]:
+            raise ConfigMismatch(f"reduced {key}: the file runs "
+                                 f"{conf.get(key)!r}, the entry says "
+                                 f"{r['here']!r}")
+        if key not in family.FIELDS:
+            raise ConfigMismatch(f"reduced {key}: no field of the program's "
+                                 f"config holds it")
+        arch_cfg = replace_field(arch_cfg, family.FIELDS[key], r["here"])
+    spec = family.spec_of(conf)
+    bad = family.program_mismatches(spec, arch_cfg)
+    if bad:
+        raise ConfigMismatch(f"program config departs from {conf['name']}: "
+                             f"{bad}")
+    return spec, arch_cfg

@@ -82,8 +82,29 @@ def serve_mfu(facts: dict) -> float | None:
 
 def train_mfu(facts: dict) -> float | None:
     """Model operations per trained token times tokens per second, over
-    the chip's peak, in %."""
+    the peak of the cell's chips, in %."""
     if "train_tok_s" not in facts or facts.get("trace") is None:
         return None
     f = costs(facts).train_flops_per_token(facts["spec"], facts["seq"])
-    return 100.0 * f * facts["train_tok_s"] / peak(facts)["bf16_flops"]
+    chips = facts["device"]["count"]
+    return 100.0 * f * facts["train_tok_s"] / (
+        chips * peak(facts)["bf16_flops"])
+
+
+#: Collective operations, and the ``-start``/``-done`` halves of each.
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+               "collective-permute", "all-to-all")
+
+
+def collective_share(facts: dict, program: str) -> float | None:
+    """Device seconds of collective operations in ``program`` over its
+    device seconds, averaged over the chips, in %."""
+    tr = facts.get("trace")
+    if tr is None:
+        return None
+    secs, runs = tr.program(program)
+    if not runs:
+        return None
+    coll = sum(s for op, s in tr.ops_of(program).items()
+               if op.startswith(COLLECTIVES))
+    return 100.0 * coll / secs

@@ -4,8 +4,9 @@ host's mesh, and one ``ContinuousBatcher`` whose ``run`` a server
 thread calls whenever requests wait.  The drivers submit requests from
 the main thread with ``submit``, on their schedule.
 
-Weights come from :mod:`weights`; the check of what was served runs
-:mod:`reference` over a sample of the finished requests.
+Weights come from :mod:`weights`, made in the plan's parameter
+shardings over the cell's mesh; the check of what was served runs the
+family's plain reference over a sample of the finished requests.
 """
 from __future__ import annotations
 
@@ -16,7 +17,6 @@ from dataclasses import dataclass
 import numpy as np
 
 import harness
-import reference
 import weights
 
 #: ``run(max_steps=...)`` evicts what is still active once its step
@@ -50,13 +50,13 @@ class Server:
     def __init__(self, ctx: harness.Context, *, slots: int, s_max: int):
         import jax
         from repro.core import analyze_plan
-        from repro.launch.mesh import host_mesh_and_spec
         from repro.launch.scheduler import ContinuousBatcher
         from repro.launch.serve import fetch_plan
+        from repro.launch.steps import sharding_tree
         from repro.models.lm import LM
 
         self.ctx, self.slots, self.s_max = ctx, slots, s_max
-        self.mesh, mspec = host_mesh_and_spec()
+        self.mesh, mspec = ctx.mesh, ctx.mesh_spec
         plan, info = fetch_plan(ctx.arch_cfg, slots=slots, s_max=s_max,
                                 cache_root=None, mesh=mspec)
         self.plan_ms = info["fetch_ms"]
@@ -64,11 +64,15 @@ class Server:
         if not lint.ok or (info["report"] and info["report"].degradations):
             ctx.note(f"plan lint {lint.summary()}")
         self.lm = LM(ctx.arch_cfg, plan=plan, mesh=self.mesh, remat="none")
-        bad = weights.check_tree(ctx.spec, self.lm.init(None, True)[0])
+        abstract, dims = self.lm.init(None, True)
+        self.layout = harness.family(ctx.spec.family).layout(ctx.spec)
+        bad = weights.check_tree(self.layout, abstract)
         if bad:
             raise RuntimeError(f"parameter layout differs: {bad}")
+        self.shardings = sharding_tree(dims, self.mesh, plan, weight=True,
+                                       shapes_tree=abstract)
         with jax.set_mesh(self.mesh):
-            self.params = weights.make(ctx.spec, ctx.seed)
+            self.params = weights.make(self.layout, ctx.seed, self.shardings)
         # The batcher's own key is used only to sample at temperature > 0.
         self.batcher = ContinuousBatcher(self.lm, self.params, slots=slots,
                                          s_max=s_max, seed=ctx.seed % 2**31)
@@ -114,7 +118,7 @@ class Server:
         from repro.launch.scheduler import ContinuousBatcher
         self.batcher = self.params = None
         with jax.set_mesh(self.mesh):
-            self.params = weights.make(self.ctx.spec, seed)
+            self.params = weights.make(self.layout, seed, self.shardings)
         self.batcher = ContinuousBatcher(self.lm, self.params,
                                          slots=self.slots, s_max=self.s_max,
                                          seed=seed % 2**31)
@@ -326,15 +330,16 @@ def token_gaps(spec, w, picked: list[Sent], s_pad: int, quant=None
     first instead (the control).  Returns (gap, tokens compared)."""
     import jax
     import jax.numpy as jnp
+    fam = harness.family(spec.family)
 
     @jax.jit
     def gaps(w, toks, served):
-        ref = reference.logits(spec, w, toks)[0]
+        ref = fam.logits(spec, w, toks)[0]
         top = jnp.max(ref, axis=-1)
         if quant is None:
             pick = served
         else:
-            pick = jnp.argmax(reference.logits(spec, w, toks, quant)[0], -1)
+            pick = jnp.argmax(fam.logits(spec, w, toks, quant)[0], -1)
         return top - jnp.take_along_axis(ref, pick[:, None], -1)[:, 0]
 
     widest, n = 0.0, 0

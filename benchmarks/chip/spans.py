@@ -17,7 +17,6 @@ the host time per decode step.
 from __future__ import annotations
 
 import re
-from bisect import bisect_right
 from collections import defaultdict
 from dataclasses import dataclass, field
 from typing import NamedTuple
@@ -168,21 +167,13 @@ def _op_seconds(dev: list[Event], hlo: dict) -> dict:
               for p, texts in hlo.items()}
     out: dict = defaultdict(lambda: defaultdict(float))
     for plane in sorted({ev.plane for ev in dev}):
-        mods = sorted((ev for ev in dev if ev.plane == plane
-                       and ev.line == trace.MODULES_LINE
-                       and trace.program_name(ev.name) in parsed),
-                      key=lambda ev: ev.start_ns)
-        starts = [m.start_ns for m in mods]
-        runs = defaultdict(list)
-        for ev in dev:
-            if ev.plane != plane or ev.line != trace.OPS_LINE:
-                continue
-            i = bisect_right(starts, ev.start_ns) - 1
-            if i >= 0 and ev.start_ns < mods[i].end_ns and not trace.op_name(
-                    ev.name).startswith(trace.CONTAINERS):
-                runs[i].append(ev)
-        for i, ops in runs.items():
-            prog = trace.program_name(mods[i].name)
+        mods = [ev for ev in dev if ev.plane == plane
+                and ev.line == trace.MODULES_LINE
+                and trace.program_name(ev.name) in parsed]
+        op_ev = [ev for ev in dev
+                 if ev.plane == plane and ev.line == trace.OPS_LINE]
+        for mod, ops in trace.program_runs(mods, op_ev):
+            prog = trace.program_name(mod.name)
             texts = {ev.name for ev in ops}
             instrs = max(parsed[prog], key=lambda c: sum(
                 t.startswith(c.get(trace.op_name(t), ("\0",))[0])

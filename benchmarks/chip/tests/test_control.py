@@ -43,11 +43,12 @@ def test_serving_control_fails(cell, tmp_path):
 def test_training_control_fails(cell, tmp_path):
     ctx = smoke.context(cell, out_dir=tmp_path)
     drv = harness.load_module("drivers", ctx.traffic["kind"])
-    job = drv.Job(ctx, drv.build(ctx))
+    built = drv.build(ctx)
+    job = drv.Job(ctx, built)
     fed, losses, g, d = job.first_steps()
     job.close()
-    ref = drv.reference_steps(ctx.spec, ctx.traffic, ctx.seed, fed)
-    ctrl = drv.reference_steps(ctx.spec, ctx.traffic, ctx.seed, fed,
+    ref = drv.reference_steps(ctx.spec, ctx.traffic, ctx.seed, fed, built)
+    ctrl = drv.reference_steps(ctx.spec, ctx.traffic, ctx.seed, fed, built,
                                quant="fp8")
     prog_nums = drv.compare(losses, g, d, *ref)
     ctrl_nums = drv.compare(*ctrl, *ref)

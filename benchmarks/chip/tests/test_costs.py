@@ -11,11 +11,16 @@ def _costs(spec):
     return harness.load_module("costs", spec.family)
 
 
+def _spec(name):
+    conf = model.load_config(name)
+    return harness.family(conf["family"]).spec_of(conf)
+
+
 @pytest.mark.parametrize("name", ["smollm-360m", "stablelm-3b"])
 def test_params_equal_the_programs_tree(name):
     from repro.configs import get_config
     from repro.models.lm import LM
-    spec = model.spec_of(model.load_config(name))
+    spec = _spec(name)
     tree, _ = LM(get_config(spec.arch)).init(None, abstract=True)
     have = sum(int(np.prod(a.shape)) for a in _leaf_list(tree))
     assert _costs(spec).params(spec) == have
@@ -27,7 +32,7 @@ def _leaf_list(tree):
 
 
 def test_smollm_counts_by_hand():
-    spec = model.spec_of(model.load_config("smollm-360m"))
+    spec = _spec("smollm-360m")
     c = _costs(spec)
     # one layer: q 960*960 + k,v 2*960*320 + o 960*960 + gate,up,down
     # 3*960*2560 = 921600 + 614400 + 921600 + 7372800
@@ -49,6 +54,6 @@ def test_smollm_counts_by_hand():
 
 
 def test_stablelm_kv_is_eight_times_smollm():
-    big = model.spec_of(model.load_config("stablelm-3b"))
+    big = _spec("stablelm-3b")
     assert big.kv_bytes_per_token == 327_680
     assert big.rot_dim == 20

@@ -1,10 +1,12 @@
 """A run with its timed path broken underneath comes out not correct:
 the harness's look for a chip is skipped, everything else of a run is
-driven at smoke size on the CPU.  Faults a one-chip cell can have: a
-token altered where it is produced, a step that returns its state
-unchanged, half of the batch left out."""
+driven at smoke size on the CPU.  Faults a cell can have: a token
+altered where it is produced, a step that returns its state unchanged,
+half of the batch left out, and on more than one chip the exchange
+between chips left out."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 import harness
@@ -14,6 +16,8 @@ SERVE_CELLS = [w["name"] for w in harness.benchmark()["workloads"]
                if harness.traffic(w["traffic"])["kind"] != "train_steps"]
 TRAIN_CELLS = [w["name"] for w in harness.benchmark()["workloads"]
                if harness.traffic(w["traffic"])["kind"] == "train_steps"]
+MESH_TRAIN_CELLS = [w["name"] for w in harness.benchmark()["workloads"]
+                    if w["name"] in TRAIN_CELLS and w["chips"] > 1]
 
 
 def _run(ctx):
@@ -73,6 +77,49 @@ def test_half_the_batch_left_out(cell, tmp_path, monkeypatch):
         def step(params, opt_state, batch, i):
             half = {k: v[:v.shape[0] // 2] for k, v in batch.items()}
             return fn(params, opt_state, half, i)
+        return step
+
+    _wrap_step(monkeypatch, drv, wrap)
+    out = _run(ctx)
+    assert not out.correct, out.checks
+
+
+def _by_data_shard(outs: list, like, mesh):
+    """Each device's part of each leaf from ``outs[k]``, ``k`` its place
+    on the mesh's data axis (a part that several data shards hold, from
+    the first of them)."""
+    axis = mesh.axis_names.index("data")
+
+    def one(ref, *parts):
+        out = np.array(parts[0])
+        owners = ref.sharding.devices_indices_map(ref.shape).items()
+        for dev, idx in sorted(owners, key=lambda kv: -np.argwhere(
+                mesh.devices == kv[0])[0][axis]):
+            k = np.argwhere(mesh.devices == dev)[0][axis]
+            out[idx] = np.asarray(parts[k])[idx]
+        return jax.device_put(out, ref.sharding)
+
+    return jax.tree.map(one, like, *outs)
+
+
+@pytest.mark.parametrize("cell", MESH_TRAIN_CELLS)
+def test_exchange_between_chips_left_out(cell, tmp_path, monkeypatch):
+    """Each data shard updates its part of the state from its own rows
+    alone, as a step whose gradient is never exchanged over the data
+    axis would."""
+    ctx = smoke.context(cell, out_dir=tmp_path)
+    drv = harness.load_module("drivers", ctx.traffic["kind"])
+    d = ctx.mesh.shape["data"]
+
+    def wrap(fn):
+        def step(params, opt_state, batch, i):
+            rows = len(batch["tokens"]) // d
+            outs = [fn(*jax.tree.map(jnp.copy, (params, opt_state)),
+                       {k: v[j * rows:(j + 1) * rows]
+                        for k, v in batch.items()}, i) for j in range(d)]
+            state = _by_data_shard([o[:2] for o in outs],
+                                   (params, opt_state), ctx.mesh)
+            return (*state, outs[0][2])
         return step
 
     _wrap_step(monkeypatch, drv, wrap)

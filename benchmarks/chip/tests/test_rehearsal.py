@@ -3,10 +3,10 @@ to end at the program's smoke size on the CPU, and the real command
 refuses to run off a TPU."""
 import json
 import os
+import re
 import shutil
 import subprocess
 import sys
-from pathlib import Path
 
 import pytest
 
@@ -22,8 +22,10 @@ ROOT = harness.ROOT
 def test_every_named_file_loads():
     kinds = set()
     for w in BENCH["workloads"]:
-        spec = model.spec_of(model.load_config(w["config"]))
-        harness.load_module("costs", spec.family)
+        conf = model.load_config(w["config"])
+        fam = harness.family(conf["family"])
+        assert fam.spec_of(conf).family == conf["family"]
+        harness.load_module("costs", conf["family"])
         t = harness.traffic(w["traffic"])
         kinds.add(t["kind"])
         assert callable(harness.load_module("drivers", t["kind"]).run)
@@ -95,39 +97,6 @@ def test_traced_serving_counts_the_servers_work(cell, tmp_path,
     assert tr.busy_work_s <= tr.busy_s
 
 
-def test_a_cell_is_added_by_files_alone(tmp_path):
-    """A copy of the benchmark gains a cell from a new traffic file, a new
-    checks file and a ``workloads`` entry; its rehearsal runs it with no
-    file of the harness edited."""
-    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
-    for p in BENCH["paths"]:
-        shutil.copytree(ROOT / p, tmp_path / p,
-                        ignore=shutil.ignore_patterns("__pycache__"))
-    (tmp_path / "src").symlink_to(ROOT / "src")
-    here = tmp_path / "benchmarks" / "chip"
-    w = dict(harness.workload(BENCH, CELLS[0]))
-    mix = json.loads((here / "traffic" / f"{w['traffic']}.json").read_text())
-    mix["about"] = "a copy of an existing mix under a new name"
-    (here / "traffic" / "added_mix.json").write_text(json.dumps(mix))
-    name = f"{w['config']}.added_mix"
-    shutil.copy(here / "checks" / f"{w['name']}.json",
-                here / "checks" / f"{name}.json")
-    bench = json.loads((tmp_path / "BENCHMARK.json").read_text())
-    bench["workloads"].append(dict(w, name=name, traffic="added_mix"))
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if w["name"] in m.get("workloads", []):
-            m["workloads"].append(name)
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    r = subprocess.run(
-        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
-         "benchmarks/chip/tests/test_rehearsal.py", "-k",
-         f"every_named or every_metric or {name}"],
-        cwd=tmp_path, env=dict(os.environ, JAX_PLATFORMS="cpu"),
-        capture_output=True, text=True, timeout=600)
-    assert r.returncode == 0, r.stdout[-3000:]
-    assert "4 passed" in r.stdout       # the new cell traced and untraced
-
-
 def _run(cwd, env_extra=None):
     env = dict(os.environ, JAX_PLATFORMS="cpu", **(env_extra or {}))
     return subprocess.run(
@@ -166,3 +135,91 @@ def test_result_line_holds_the_contracts_keys():
     assert list(line)[-1] == "checks"
     assert line["metrics"]["setup_s"] == {"value": 9.0, "unit": "s"}
     json.dumps(line)
+
+
+#: Compiles, on one CPU device, the train driver's step for the one-chip
+#: train cell and ``repro.launch.train.build``'s step for the same job,
+#: and prints each optimised HLO text as JSON.  ``build`` plans for every
+#: device it sees, so this runs in a process that sees one.
+_STEPS = r"""
+import argparse, json, sys
+import jax
+import harness, smoke
+from repro.configs.base import ShapeSpec
+from repro.launch import train
+from repro.launch.steps import batch_specs
+ctx = smoke.context(sys.argv[1])
+t = ctx.traffic
+b = harness.load_module("drivers", "train_steps").build(ctx)
+params = b.lm.init(None, True)[0]
+state = jax.eval_shape(b.opt.init, params)
+batch = batch_specs(b.cfg, ShapeSpec("cli", t["seq"], t["batch"], "train"))[0]
+def text(fn, mesh):
+    with jax.set_mesh(mesh):
+        return fn.lower(params, state, batch, 0).compile().as_text()
+out = {"driver": text(b.step_fn, b.mesh)}
+for remat in (t["remat"], "none"):
+    args = argparse.Namespace(
+        arch=ctx.conf["arch"], smoke=True, steps=t["schedule_steps"],
+        batch=t["batch"], seq=t["seq"], lr=t["lr"], remat=remat, fsdp=False)
+    _, _, mesh, _, _, _, step = train.build(args)
+    out[remat] = text(step, mesh)
+print(json.dumps(out))
+"""
+
+
+def _strip(line: str) -> str:
+    """``line`` without its ``metadata``, ``sharding`` and
+    ``frontend_attributes`` groups (braces nest inside them)."""
+    for key in (", metadata={", ", sharding={", ", frontend_attributes={"):
+        while (i := line.find(key)) >= 0:
+            j, depth = i + len(key), 1
+            while depth:
+                depth += {"{": 1, "}": -1}.get(line[j], 0)
+                j += 1
+            line = line[:i] + line[j:]
+    return line
+
+
+def _instructions(hlo: str) -> list[str]:
+    """An optimised HLO text's computations, without the module header,
+    the source tables, metadata and sharding annotations, and with each
+    name numbered in order of first use."""
+    out, skip = [], False
+    for line in hlo.splitlines():
+        if line.startswith("FileNames"):
+            skip = True
+        elif line.startswith(("%", "ENTRY")):
+            skip = False
+        if not skip and not line.startswith("HloModule"):
+            out.append(_strip(line))
+    names: dict = {}
+
+    def rename(m):
+        return names.setdefault(m.group(0), f"%v{len(names)}")
+
+    return [re.sub(r"%[\w.\-]+", rename, line) for line in out]
+
+
+@pytest.mark.parametrize("cell", [
+    w["name"] for w in BENCH["workloads"] if w["chips"] == 1
+    and harness.traffic(w["traffic"])["kind"] == "train_steps"])
+def test_train_step_is_the_programs(cell):
+    """The train driver builds ``train.build``'s step itself (for the
+    cell's cut config and mesh); on one chip it compiles to the program's
+    step instruction for instruction, and a step built otherwise (no
+    remat) does not."""
+    tests = harness.HERE / "tests"
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=1",
+               PYTHONPATH=os.pathsep.join(
+                   [str(tests), str(harness.HERE), str(ROOT / "src")]))
+    r = subprocess.run([sys.executable, "-c", _STEPS, cell], cwd=ROOT,
+                       env=env, capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    texts = json.loads(r.stdout.strip().splitlines()[-1])
+    remat = harness.traffic(harness.workload(BENCH, cell)["traffic"])["remat"]
+    assert remat != "none"
+    driver = _instructions(texts["driver"])
+    assert driver == _instructions(texts[remat])
+    assert driver != _instructions(texts["none"])

@@ -10,6 +10,7 @@ from __future__ import annotations
 import glob
 import os
 import re
+from bisect import bisect_right
 from collections import defaultdict
 from dataclasses import dataclass, field
 from typing import NamedTuple
@@ -39,6 +40,7 @@ class Summary:
     work_s: float = 0.0                # the window's stretches with work
     busy_work_s: float = 0.0           # busy in them, mean over devices
     programs: dict = field(default_factory=dict)   # name -> [seconds, runs]
+    program_ops: dict = field(default_factory=dict)  # name -> {op: seconds}
     top_ops: list = field(default_factory=list)    # [[name, seconds]]
     idle_gaps: list = field(default_factory=list)  # [[host activity, s]]
 
@@ -59,6 +61,16 @@ class Summary:
             if name.startswith(prefix):
                 t, n = t + s, n + runs
         return t, n
+
+    def ops_of(self, prefix: str) -> dict[str, float]:
+        """Device seconds of each operation run inside a program whose
+        name starts with ``prefix``."""
+        out: dict[str, float] = defaultdict(float)
+        for name, ops in self.program_ops.items():
+            if name.startswith(prefix):
+                for op, s in ops.items():
+                    out[op] += s
+        return dict(out)
 
 
 def find_trace(log_dir: str) -> str:
@@ -156,6 +168,22 @@ def _host_activity(host: list[Event], s: float, e: float) -> str:
     return max(cover, key=lambda c: c[0])[1].name
 
 
+def program_runs(mod_ev: list[Event], op_ev: list[Event]
+                 ) -> list[tuple[Event, list[Event]]]:
+    """Each run of a program on one device: its module event and the
+    operations that start inside it (a loop's or call's own event spans
+    the operations it runs and is left out)."""
+    mods = sorted(mod_ev, key=lambda ev: ev.start_ns)
+    starts = [m.start_ns for m in mods]
+    runs = defaultdict(list)
+    for ev in op_ev:
+        i = bisect_right(starts, ev.start_ns) - 1
+        if i >= 0 and ev.start_ns < mods[i].end_ns and not op_name(
+                ev.name).startswith(CONTAINERS):
+            runs[i].append(ev)
+    return [(mods[i], ops) for i, ops in sorted(runs.items())]
+
+
 def summarize(events: list[Event], t0_ns: float, t1_ns: float,
               n_top: int = 10, work=None) -> Summary:
     """Reduce the events of ``[t0_ns, t1_ns]`` (the traced window).  The
@@ -168,6 +196,7 @@ def summarize(events: list[Event], t0_ns: float, t1_ns: float,
                   for s, e in ([(t0_ns, t1_ns)] if work is None else work)
                   if min(e, t1_ns) > max(s, t0_ns))
     busy, busy_work, programs, ops = 0.0, 0.0, {}, defaultdict(float)
+    program_ops: dict = defaultdict(lambda: defaultdict(float))
     all_gaps = []
     for plane in planes:
         mine = [ev for ev in dev if ev.plane == plane]
@@ -187,6 +216,10 @@ def summarize(events: list[Event], t0_ns: float, t1_ns: float,
             name = op_name(ev.name)
             if not name.startswith(CONTAINERS):
                 ops[name] += ev.dur_ns * 1e-9
+        for mod, run in program_runs(mod_ev, op_ev):
+            for ev in run:
+                program_ops[program_name(mod.name)][op_name(ev.name)] += \
+                    ev.dur_ns * 1e-9
     n = max(len(planes), 1)
     host = [ev for ev in events if not ev.plane.startswith(DEVICE_PREFIX)]
     longest = sorted(all_gaps, key=lambda g: g[0] - g[1])[:n_top]
@@ -195,6 +228,8 @@ def summarize(events: list[Event], t0_ns: float, t1_ns: float,
         devices=len(planes), work_s=union_ns(work) * 1e-9,
         busy_work_s=busy_work * 1e-9 / n,
         programs={k: [v[0] / n, v[1] // n] for k, v in programs.items()},
+        program_ops={p: {k: v / n for k, v in o.items()}
+                     for p, o in program_ops.items()},
         top_ops=[[k, v / n] for k, v in sorted(
             ops.items(), key=lambda kv: -kv[1])[:n_top]],
         idle_gaps=[[_host_activity(host, s, e), (e - s) * 1e-9]
